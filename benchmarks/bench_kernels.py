@@ -7,20 +7,15 @@ rows as a machine-readable artifact conforming to the frozen
 ``repro.bench_kernels`` schema (``benchmarks/schema.py``, documented
 in ``benchmarks/README.md``).
 
-The sharded lane (``kernel/*_sharded_*`` rows) needs >= 4 devices;
-on a single-device host it respawns itself in a subprocess with 4
-forced CPU host devices (``launch.mesh.host_device_env``) and merges
-the child's rows, so every artifact records the multi-device story.
-``--no-sharded`` skips it; ``--sharded-child`` is the internal child
-mode.
+The sharded lane (``kernel/*_sharded_*`` rows) runs in this process
+when it sees >= 4 devices (on a CPU host: ``XLA_FLAGS=
+--xla_force_host_platform_device_count=4``); otherwise it emits a
+``kernel/gemm_sharded_skipped`` row saying it did not run.
+``--no-sharded`` skips it.
 """
 from __future__ import annotations
 
 import json
-import os
-import subprocess
-import sys
-import tempfile
 import time
 
 import jax
@@ -29,7 +24,6 @@ import numpy as np
 
 from repro.analysis import contracts
 from repro.analysis.hlo_rules import (
-    CrossLoweringUnavailable,
     count_custom_calls,
     operand_sized_ops,
     tpu_lowering_text,
@@ -53,7 +47,7 @@ from repro.kernels.ops import (
 )
 from repro.kernels.ref import passthrough_mixed
 from repro.launch.hlo_analysis import analyze_hlo
-from repro.launch.mesh import host_device_env
+from repro.launch.mesh import make_mesh
 
 from .common import csv_row
 from .schema import make_artifact
@@ -191,10 +185,7 @@ def _bench_nvfp4_gemm(rows, rng, smoke: bool):
     iters = 3 if smoke else 10
     us_l = _time(jax.jit(legacy), x, iters=iters)
     us_f = _time(jax.jit(fused_xla), x, iters=iters)
-    try:
-        launches = _tpu_kernel_launches(fused_pallas, x)
-    except Exception:  # older jax without cross-platform lowering
-        launches = -1
+    launches = _tpu_kernel_launches(fused_pallas, x)
     tag = f"{M}x{N}x{K}"
     rows.append(csv_row(
         f"kernel/gemm_nvfp4_xla_{tag}", us_f,
@@ -242,10 +233,7 @@ def _bench_mixed_gemm(rows, rng, smoke: bool, recipe: str = "sub3"):
         us_f = _time(jax.jit(fused_xla), x, iters=iters)
         by_l, ps_l = _hlo_stats(legacy, x)
         by_f, ps_f = _hlo_stats(fused_xla, x)
-        try:
-            launches = _tpu_kernel_launches(fused_pallas, x)
-        except Exception:  # older jax without cross-platform lowering
-            launches = -1
+        launches = _tpu_kernel_launches(fused_pallas, x)
         tag = f"{M}x{N}x{K}"
         rows.append(
             csv_row(f"kernel/gemm_legacy_dequant_{tag}", us_l,
@@ -340,37 +328,34 @@ def _bench_quantize_pack(rows, rng, smoke: bool):
                 )
                 return mo.payload_q, mo.payload_bf16
 
-            try:
-                txt_f = tpu_lowering_text(fused_pl, x)
-                launches = count_custom_calls(txt_f)
-                ops_f = operand_sized_ops(txt_f, x.shape)
-                ops_sel = operand_sized_ops(
-                    tpu_lowering_text(select_pl, x), x.shape
+            txt_f = tpu_lowering_text(fused_pl, x)
+            launches = count_custom_calls(txt_f)
+            ops_f = operand_sized_ops(txt_f, x.shape)
+            ops_sel = operand_sized_ops(
+                tpu_lowering_text(select_pl, x), x.shape
+            )
+            ops_2 = operand_sized_ops(
+                tpu_lowering_text(two_pass_pl, x), x.shape
+            )
+            pack_ops = ops_f - ops_sel
+            # The acceptance pins live in the contract registry
+            # (repro.analysis.contracts): one fused launch, zero
+            # operand-sized XLA packing ops on top of selection.
+            lo, hi = contracts.SINGLE_LAUNCH
+            if not lo <= launches <= hi:
+                raise AssertionError(
+                    f"quantize_pack {recipe} {mkn}: {launches} "
+                    f"launches outside {contracts.SINGLE_LAUNCH}"
                 )
-                ops_2 = operand_sized_ops(
-                    tpu_lowering_text(two_pass_pl, x), x.shape
+            if pack_ops > contracts.MAX_PACK_OPS_OVER_SELECT:
+                raise AssertionError(
+                    f"quantize_pack {recipe} {mkn}: {pack_ops} "
+                    "operand-sized packing op(s) over bare "
+                    "selection (max "
+                    f"{contracts.MAX_PACK_OPS_OVER_SELECT})"
                 )
-                pack_ops = ops_f - ops_sel
-                # The acceptance pins live in the contract registry
-                # (repro.analysis.contracts): one fused launch, zero
-                # operand-sized XLA packing ops on top of selection.
-                lo, hi = contracts.SINGLE_LAUNCH
-                if not lo <= launches <= hi:
-                    raise AssertionError(
-                        f"quantize_pack {recipe} {mkn}: {launches} "
-                        f"launches outside {contracts.SINGLE_LAUNCH}"
-                    )
-                if pack_ops > contracts.MAX_PACK_OPS_OVER_SELECT:
-                    raise AssertionError(
-                        f"quantize_pack {recipe} {mkn}: {pack_ops} "
-                        "operand-sized packing op(s) over bare "
-                        "selection (max "
-                        f"{contracts.MAX_PACK_OPS_OVER_SELECT})"
-                    )
-                pack_ops = max(pack_ops, 0)
-                twopass_pack_ops = ops_2 - ops_sel
-            except CrossLoweringUnavailable:  # older jax
-                launches, pack_ops, twopass_pack_ops = -1, -1, -1
+            pack_ops = max(pack_ops, 0)
+            twopass_pack_ops = ops_2 - ops_sel
             # No wall "speedup" field on purpose: on the xla backend
             # the fused entry point IS the two-pass reference, so the
             # walls only track host drift. The fusion's win is the
@@ -520,16 +505,16 @@ def _sharded_rows(smoke: bool):
     single-device baselines, with per-shard fused-kernel launch counts
     from the TPU cross-lowering of the shard-local computation.
 
-    Own fixed seed so the in-process and --sharded-child paths bench
-    identical data."""
+    Own fixed seed so the lane's data does not depend on the lanes run
+    before it."""
     from jax.sharding import PartitionSpec as P
 
-    from repro.core.collectives import compat_shard_map
+    from repro.core.collectives import shard_map_unchecked
 
     rng = np.random.default_rng(7)
     rows = []
     ndev = 4
-    mesh = jax.make_mesh((ndev,), ("data",))
+    mesh = make_mesh((ndev,), ("data",))
     M = N = K = 512
     bm = 128
     pol = MoRPolicy(recipe="sub3", partition="block", backend="xla")
@@ -559,11 +544,8 @@ def _sharded_rows(smoke: bool):
             passthrough_mixed(a, (bm, bm)), mo, backend="pallas"
         )
 
-    try:
-        per_shard = _tpu_kernel_launches(pallas_gemm, x[: M // ndev])
-        rep_launches = _tpu_kernel_launches(pallas_gemm, x)
-    except Exception:  # older jax without cross-platform lowering
-        per_shard = rep_launches = -1
+    per_shard = _tpu_kernel_launches(pallas_gemm, x[: M // ndev])
+    rep_launches = _tpu_kernel_launches(pallas_gemm, x)
     tag = f"{M}x{N}x{K}"
     rows.append(csv_row(
         f"kernel/gemm_sharded_row_data{ndev}_{tag}", us_sh,
@@ -595,7 +577,7 @@ def _sharded_rows(smoke: bool):
     xq = jnp.asarray(rng.standard_normal((1024, 1024)), jnp.bfloat16)
     us_q1 = _time(jax.jit(lambda a: mor_quantize(a, qpol)[0]), xq,
                   iters=iters)
-    sm = jax.jit(compat_shard_map(
+    sm = jax.jit(shard_map_unchecked(
         lambda a: mor_quantize(a, qpol_sh)[0], mesh,
         P("data", None), P("data", None),
     ))
@@ -609,47 +591,21 @@ def _sharded_rows(smoke: bool):
 
 
 def _bench_sharded(rows, smoke: bool):
-    """Run the sharded lane here if this process already has >= 4
-    devices, else respawn in a 4-forced-host-device subprocess and
-    merge its rows (XLA fixes the device count at backend init)."""
-    if len(jax.devices()) >= 4:
+    """Run the sharded lane in this process when it has >= 4 devices;
+    otherwise record that it did not run."""
+    n = len(jax.devices())
+    if n >= 4:
         rows.extend(_sharded_rows(smoke))
         return
-    with tempfile.TemporaryDirectory() as td:
-        tmp = os.path.join(td, "sharded.json")
-        cmd = [sys.executable, "-m", "benchmarks.bench_kernels",
-               "--sharded-child", "--json", tmp]
-        if smoke:
-            cmd.append("--smoke")
-        try:
-            proc = subprocess.run(
-                cmd, env=host_device_env(4), capture_output=True,
-                text=True, timeout=900, cwd=os.getcwd(),
-            )
-            if proc.returncode != 0:
-                raise RuntimeError(proc.stderr[-500:])
-            with open(tmp) as f:
-                child = json.load(f)
-            rows.extend(
-                csv_row(r["name"], r["us"], r["derived"])
-                for r in child["rows"]
-            )
-        except Exception as e:  # never fail the whole bench
-            reason = str(e).replace(";", ",").replace("=", ":")
-            reason = " ".join(reason.split())[:120] or "unknown"
-            rows.append(csv_row(
-                "kernel/gemm_sharded_skipped", 0.0,
-                f"skipped=1;reason={reason}",
-            ))
+    rows.append(csv_row(
+        "kernel/gemm_sharded_skipped", 0.0,
+        f"skipped=1;reason=devices:{n}<4",
+    ))
 
 
-def main(smoke: bool = False, sharded: bool = True,
-         sharded_only: bool = False, recipe: str = "sub3"):
+def main(smoke: bool = False, sharded: bool = True, recipe: str = "sub3"):
     rows = []
     rng = np.random.default_rng(0)
-
-    if sharded_only:
-        return _sharded_rows(smoke), None
 
     # Mixed-representation block GEMM vs legacy dequant+matmul.
     _bench_mixed_gemm(rows, rng, smoke, recipe=recipe)
@@ -698,10 +654,7 @@ def main(smoke: bool = False, sharded: bool = True,
         us_f = _time(jax.jit(fused_xla), x)
         by_l, ps_l = _hlo_stats(_three_pass_sub3, x)
         by_f, ps_f = _hlo_stats(fused_xla, x)
-        try:
-            launches = _tpu_kernel_launches(fused_pallas, x)
-        except Exception:  # older jax without cross-platform lowering
-            launches = -1
+        launches = _tpu_kernel_launches(fused_pallas, x)
         tag = f"{mkn[0]}x{mkn[1]}"
         rows.append(
             csv_row(f"kernel/sub3_3pass_{tag}", us_l,
@@ -766,7 +719,7 @@ def main(smoke: bool = False, sharded: bool = True,
     _bench_analysis_contracts(rows)
     _bench_robust_guard(rows)
 
-    # Multi-device sharded lane (possibly via a forced-device child).
+    # Multi-device sharded lane (in this process, >= 4 devices).
     if sharded:
         _bench_sharded(rows, smoke)
     return rows, None
@@ -802,7 +755,7 @@ def _bench_robust_guard(rows):
     # tests/test_robust_chaos.py::test_every_fault_class_has_chaos_coverage
     rows.append(csv_row(
         "kernel/robust_guard", 0.0,
-        f"guard_clean_pack_ops={report.counters.get('tpu_pack_ops', -1)};"
+        f"guard_clean_pack_ops={report.counters['tpu_pack_ops']};"
         f"guard_contract_violations={len(report.violations)};"
         f"fault_classes_registered={len(specs)};"
         f"fault_classes_covered={covered}",
@@ -819,9 +772,6 @@ if __name__ == "__main__":
                     help="write rows as a repro.bench_kernels artifact")
     ap.add_argument("--no-sharded", action="store_true",
                     help="skip the multi-device sharded lane")
-    ap.add_argument("--sharded-child", action="store_true",
-                    help="internal: run only the sharded lane "
-                         "(spawned with forced host devices)")
     ap.add_argument("--recipe", default="sub3",
                     choices=("sub2", "sub3", "sub4"),
                     help="MoR recipe for the mixed-GEMM lane "
@@ -830,7 +780,6 @@ if __name__ == "__main__":
     out_rows = main(
         smoke=args.smoke,
         sharded=not args.no_sharded,
-        sharded_only=args.sharded_child,
         recipe=args.recipe,
     )[0]
     for row in out_rows:

@@ -12,6 +12,7 @@ import numpy as np
 
 from repro.configs import get_config, reduced
 from repro.core import MoRPolicy, TENSOR_MOR
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import init_params
 from repro.serve import Engine, Request, ServeConfig
 
@@ -23,6 +24,7 @@ def main():
     ap.add_argument("--slots", type=int, default=3)
     ap.add_argument("--max-tokens", type=int, default=12)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = dataclasses.replace(reduced(get_config(args.arch)), vocab=512)
     params = init_params(cfg, jax.random.PRNGKey(0))

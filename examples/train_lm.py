@@ -19,6 +19,7 @@ import numpy as np
 from repro.configs import get_config, reduced
 from repro.core import BF16_BASELINE, paper_default
 from repro.data import DataConfig
+from repro.launch.compile_cache import enable_compile_cache
 from repro.optim import AdamWConfig
 from repro.train import Trainer, TrainerConfig, TrainConfig
 
@@ -61,6 +62,7 @@ def main():
     ap.add_argument("--ckpt", default=None)
     ap.add_argument("--lr", type=float, default=3e-3)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg, seq, batch, steps = build_cfg(args.arch, args.preset)
     steps = args.steps or steps

@@ -18,10 +18,8 @@ structure, forbidden op families, donation markers) and
 accumulation dtypes). Registering a new entry point is one
 :func:`register` call -- see docs/analysis.md.
 
-Cross-lowering rules degrade gracefully on jax versions without the
-cross-platform lowering API: the report carries the ``-1``
-lane-unavailable sentinel instead of failing (same convention as the
-bench rows).
+A cross-lowering that fails raises: a kernel the TPU lowering refuses
+is a broken contract, not a missing lane.
 """
 from __future__ import annotations
 
@@ -31,7 +29,7 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import core as jcore
+from jax.extend import core as jcore
 
 from . import hlo_rules
 from .jaxpr_lint import lint_payload_flow
@@ -254,28 +252,20 @@ def check_contract(contract: Contract) -> ContractReport:
         or contract.max_pack_ops_over_baseline is not None
     )
     if wants_tpu:
-        try:
-            tpu_txt = hlo_rules.tpu_lowering_text(case.fn, *case.args)
-        except hlo_rules.CrossLoweringUnavailable:
-            tpu_txt = None
+        tpu_txt = hlo_rules.tpu_lowering_text(case.fn, *case.args)
 
     if contract.custom_calls is not None:
         rules += 1
-        if tpu_txt is None:
-            counters["tpu_kernel_launches"] = -1
-        else:
-            lo, hi = contract.custom_calls
-            n = hlo_rules.count_custom_calls(tpu_txt)
-            counters["tpu_kernel_launches"] = n
-            if not lo <= n <= hi:
-                violations.append(
-                    f"custom calls: {n} outside [{lo}, {hi}]"
-                )
+        lo, hi = contract.custom_calls
+        n = hlo_rules.count_custom_calls(tpu_txt)
+        counters["tpu_kernel_launches"] = n
+        if not lo <= n <= hi:
+            violations.append(f"custom calls: {n} outside [{lo}, {hi}]")
 
     if contract.max_pack_ops_over_baseline is not None:
         rules += 1
-        if tpu_txt is None or case.baseline_fn is None:
-            counters["tpu_pack_ops"] = -1
+        if case.baseline_fn is None:
+            violations.append("max_pack_ops_over_baseline needs a baseline")
         else:
             base_txt = hlo_rules.tpu_lowering_text(
                 case.baseline_fn, *case.args

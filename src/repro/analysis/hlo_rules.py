@@ -22,7 +22,6 @@ from typing import Callable, List, Sequence, Tuple
 import jax
 
 __all__ = [
-    "CrossLoweringUnavailable",
     "tpu_lowering_text",
     "lowering_text",
     "compiled_hlo_text",
@@ -36,27 +35,14 @@ __all__ = [
 ]
 
 
-class CrossLoweringUnavailable(RuntimeError):
-    """This jax has no cross-platform lowering API (``lowering_platforms``
-    keyword): structural TPU rules cannot be evaluated on this host."""
-
-
 def tpu_lowering_text(fn: Callable, *args) -> str:
     """StableHLO text of ``jit(fn)(*args)`` cross-lowered for TPU.
 
     Works on any host (no TPU needed): the Pallas path becomes
-    ``tpu_custom_call`` ops in the text. Raises
-    :class:`CrossLoweringUnavailable` on jax versions without the
-    cross-platform lowering API (callers translate that into a skip or
-    the ``-1`` lane-unavailable sentinel).
+    ``tpu_custom_call`` ops in the text.
     """
-    try:
-        traced = jax.jit(fn).trace(*args)
-        return traced.lower(lowering_platforms=("tpu",)).as_text()
-    except TypeError as e:
-        raise CrossLoweringUnavailable(
-            "this jax has no cross-platform lowering API"
-        ) from e
+    traced = jax.jit(fn).trace(*args)
+    return traced.lower(lowering_platforms=("tpu",)).as_text()
 
 
 def lowering_text(fn: Callable, *args, donate_argnums=()) -> str:

@@ -39,13 +39,9 @@ import re
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
-from jax import core as jcore
 from jax import tree_util as jtu
-
-try:  # jax internal, but stable across the versions this repo supports
-    from jax._src import source_info_util as _siu
-except ImportError:  # pragma: no cover - very old jax
-    _siu = None
+from jax._src import source_info_util as _siu
+from jax.extend import core as jcore
 
 __all__ = [
     "PAYLOAD_LANE_REGEX",
@@ -140,12 +136,7 @@ def _eqn_source_files(eqn) -> List[str]:
 
 
 def _eqn_summary(eqn) -> str:
-    if _siu is not None:
-        try:
-            return _siu.summarize(eqn.source_info)
-        except Exception:  # pragma: no cover
-            pass
-    return "<unknown>"
+    return _siu.summarize(eqn.source_info)
 
 
 def _is_sanctioned(eqn, sanctioned: Sequence[str]) -> bool:

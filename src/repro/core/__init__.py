@@ -49,7 +49,7 @@ from .partition import (
     Partition,
     block_amax,
 )
-from .collectives import compat_shard_map, pmax_over, psum_over
+from .collectives import pmax_over, psum_over, shard_map_unchecked
 from .policy import (
     BF16_BASELINE,
     SUBTENSOR2_MOR,
@@ -81,6 +81,6 @@ __all__ = [
     "BF16_BASELINE", "SUBTENSOR2_MOR", "SUBTENSOR3_MOR", "SUBTENSOR4_MOR",
     "TENSOR_MOR", "MoRDotPolicy", "MoRPolicy", "paper_default",
     "with_mesh_axes",
-    "compat_shard_map", "pmax_over", "psum_over",
+    "pmax_over", "psum_over", "shard_map_unchecked",
     "MoRStatsTracker", "RelErrHistogram",
 ]

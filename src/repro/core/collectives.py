@@ -21,24 +21,17 @@ import jax.numpy as jnp
 
 __all__ = [
     "psum_over", "pmax_over", "global_size", "all_gather_over",
-    "compat_shard_map",
+    "shard_map_unchecked",
 ]
 
 
-def compat_shard_map(f, mesh, in_specs, out_specs):
-    """jax.shard_map across jax versions (with replication checks off:
-    MoR bodies produce device-invariant stats via explicit psums, which
-    the static replication checker cannot see through)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=False,
-        )
-    from jax.experimental.shard_map import shard_map as _sm
-
-    return _sm(
+def shard_map_unchecked(f, mesh, in_specs, out_specs):
+    """``jax.shard_map`` with replication checks off: MoR bodies produce
+    device-invariant stats via explicit psums, which the static
+    replication checker cannot see through."""
+    return jax.shard_map(
         f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=False,
+        check_vma=False,
     )
 
 

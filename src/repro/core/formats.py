@@ -13,10 +13,11 @@ NVFP4: E2M1 4-bit payload (magnitudes {0, 0.5, 1, 1.5, 2, 3, 4, 6})
        scale replaced by the per-block Alg. 1 GAM scale).
 BF16:  passthrough (the "original precision" fallback).
 
-FP8 casts go through ml_dtypes-backed jnp dtypes with
-round-to-nearest-even; we clamp to +-max first so no overflow-to-NaN
-can occur (GAM scaling guarantees no saturation anyway -- the clamp is
-a safety net and is what real TPU/NV cast units do in saturating mode).
+FP8 fake quantization (:func:`round_to_fp8`) is round-to-nearest-even
+bit arithmetic, bit-identical to the ml_dtypes-backed jnp casts that
+real packing uses; we clamp to +-max first so no overflow-to-NaN can
+occur (GAM scaling guarantees no saturation anyway -- the clamp is a
+safety net and is what real TPU/NV cast units do in saturating mode).
 The E2M1 payload has no jnp storage dtype on this jax, so
 :func:`round_to_e2m1` implements the RNE grid snap with exact
 power-of-two bit arithmetic (validated bit-for-bit against
@@ -33,7 +34,7 @@ import jax.numpy as jnp
 
 __all__ = [
     "FormatSpec", "E4M3", "E5M2", "BF16", "NVFP4", "FORMATS",
-    "cast_to_format", "cast_to_nvfp4", "round_to_e2m1",
+    "cast_to_format", "cast_to_nvfp4", "round_to_e2m1", "round_to_fp8",
     "encode_e2m1", "decode_e2m1",
     "NVFP4_MICRO", "E2M1_AMAX",
 ]
@@ -171,25 +172,16 @@ def encode_e2m1(v: jnp.ndarray) -> jnp.ndarray:
     return code | (sign << 3)
 
 
-def decode_e2m1(code: jnp.ndarray, dtype=jnp.float32) -> jnp.ndarray:
-    """4-bit E2M1 codes (int) -> grid values. Select-only (kernel-safe).
-
-    ``dtype`` is the arithmetic/output dtype: every E2M1 grid value
-    (and its sign flip) is exact in bf16 and wider, so a bf16 decode is
-    bit-identical to the f32 one after any downstream cast -- the GEMM
-    kernel decodes straight to the storage dtype at half the vector
-    register width.
-    """
+def decode_e2m1(code: jnp.ndarray) -> jnp.ndarray:
+    """4-bit E2M1 codes (int) -> f32 grid values. Select-only
+    (kernel-safe); every grid value is exact in bf16 and wider."""
     c = code.astype(jnp.int32)
     m = c & 7
     mag = jnp.where(
         m < 4,
-        m.astype(dtype) * jnp.asarray(0.5, dtype),
-        (jnp.asarray(1.0, dtype)
-         + jnp.asarray(0.5, dtype) * (m & 1).astype(dtype))
-        * jnp.where(
-            m >= 6, jnp.asarray(4.0, dtype), jnp.asarray(2.0, dtype)
-        ),
+        m.astype(jnp.float32) * 0.5,
+        (1.0 + 0.5 * (m & 1).astype(jnp.float32))
+        * jnp.where(m >= 6, 4.0, 2.0),
     )
     return jnp.where((c >> 3) == 1, -mag, mag)
 
@@ -240,5 +232,28 @@ def cast_to_format(x: jnp.ndarray, fmt: FormatSpec) -> jnp.ndarray:
         return cast_to_nvfp4(x)
     if fmt.is_passthrough:
         return x.astype(jnp.bfloat16).astype(jnp.float32)
-    clipped = jnp.clip(x, -fmt.amax, fmt.amax)
-    return clipped.astype(fmt.dtype).astype(jnp.float32)
+    return round_to_fp8(x, fmt)
+
+
+def round_to_fp8(x: jnp.ndarray, fmt: FormatSpec) -> jnp.ndarray:
+    """RNE snap of f32 ``x`` to the fp8 grid of ``fmt``, saturating at
+    +-``fmt.amax``; NaN stays NaN.
+
+    Bit arithmetic on the f32 pattern (normal range: round the mantissa
+    to ``fmt.mantissa_bits`` bits) plus one ``jnp.round`` on the fixed
+    subnormal grid, bit-identical to the saturating ``ml_dtypes`` cast
+    (tests/test_core_gam.py). Not ``x.astype(fp8).astype(f32)``: XLA's
+    TPU compiler drops a convert pair f32 -> narrower float -> f32 (it
+    allows excess precision; measured on a TPU v5e for fp8 and bf16), so
+    the XLA lowering would not quantize at all on the chip.
+    """
+    x = jnp.clip(x.astype(jnp.float32), -fmt.amax, fmt.amax)
+    shift = 23 - fmt.mantissa_bits
+    bits = jax.lax.bitcast_convert_type(x, jnp.uint32)
+    bits = bits + jnp.uint32((1 << (shift - 1)) - 1) + ((bits >> shift) & 1)
+    normal = jax.lax.bitcast_convert_type(
+        bits & jnp.uint32((0xFFFFFFFF << shift) & 0xFFFFFFFF), jnp.float32
+    )
+    sub = jnp.round(x / fmt.min_subnormal) * fmt.min_subnormal
+    out = jnp.where(jnp.abs(x) < fmt.min_normal, sub, normal)
+    return jnp.where(jnp.isnan(x), x, out)

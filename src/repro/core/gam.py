@@ -33,6 +33,7 @@ from .partition import Partition, block_amax
 __all__ = ["GamScales", "split_mantissa_exponent", "compute_scales", "scales_from_bmax", "exp2i", "E8M0_BIAS"]
 
 E8M0_BIAS = 127
+_F32_MAX = float(jnp.finfo(jnp.float32).max)
 
 
 class GamScales(NamedTuple):
@@ -109,8 +110,12 @@ def scales_from_bmax(
     safe_g = jnp.where(g_ok, g_amax, 1.0)
     safe_b = jnp.where((bmax > 0) & jnp.isfinite(bmax), bmax, safe_g)
 
-    s_g = fmt.amax / safe_g
-    s_b = fmt.amax / safe_b  # ideal per-block FP32 scale
+    # Ideal FP32 scales, capped at f32 max: below an amax of
+    # fmt.amax / f32max (~1e-36 for E4M3) the quotient overflows to Inf,
+    # and an Inf scale maps the block to Inf/NaN. The capped scale
+    # still lands in exp2i's domain and keeps the invariant.
+    s_g = jnp.minimum(fmt.amax / safe_g, _F32_MAX)
+    s_b = jnp.minimum(fmt.amax / safe_b, _F32_MAX)
 
     if algo == "fp32_amax":
         scale = s_b.astype(jnp.float32)

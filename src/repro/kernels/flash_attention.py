@@ -28,11 +28,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams; support both.
-_CompilerParams = getattr(
-    pltpu, "CompilerParams", getattr(pltpu, "TPUCompilerParams", None)
-)
-
 __all__ = ["flash_attention_fwd"]
 
 _NEG = -1e30
@@ -187,7 +182,7 @@ def flash_attention_fwd(
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, d), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,

@@ -22,11 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams; support both.
-_CompilerParams = getattr(
-    pltpu, "CompilerParams", getattr(pltpu, "TPUCompilerParams", None)
-)
-
 __all__ = ["fp8_gemm"]
 
 
@@ -91,7 +86,7 @@ def fp8_gemm(
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,

@@ -25,6 +25,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["gam_quant_blocks"]
 
+_F32_MAX = 3.4028235e38  # finfo(f32).max
+
 
 def _split_me(s):
     """Bit-level (mantissa in [1,2), exponent) of positive f32 (1, 1) s.
@@ -59,7 +61,7 @@ def _kernel(mg_ref, x_ref, out_ref, exp_ref, err_ref, cnt_ref,
     # vectors (Mosaic's tpu.bitcast rejects scalars).
     bmax = jnp.max(jnp.abs(x), axis=(0, 1), keepdims=True)
     safe_b = jnp.where(bmax > 0, bmax, 1.0)
-    s_b = q_amax / safe_b
+    s_b = jnp.minimum(q_amax / safe_b, _F32_MAX)  # core.gam's cap
     m_b, e_b = _split_me(s_b)
 
     if algo == "gam":

@@ -80,11 +80,6 @@ from .ref import (
     nvfp4_block_capable,
 )
 
-# jax renamed TPUCompilerParams -> CompilerParams; support both.
-_CompilerParams = getattr(
-    pltpu, "CompilerParams", getattr(pltpu, "TPUCompilerParams", None)
-)
-
 __all__ = ["mixed_gemm_blocks", "DECODE_CACHE_BUDGET", "decode_cache_bytes"]
 
 # VMEM budget for the k-keyed A-decode cache (f32 stripes); past this
@@ -113,18 +108,19 @@ def _decode(q, bf, nib, ms, tag, scale, has_nv: bool, g0=0):
     f8 = (jnp.where(tag == TAG_E5M2, q5, q4) / scale).astype(st_dtype)
     out = jnp.where(tag == TAG_BF16, bf, f8)
     if has_nv:
-        # Unpack row-halved E2M1 nibbles straight to the storage dtype
-        # (grid values and the vals * micro-scale products are exact in
-        # bf16 -- <= 5 significand bits), expand micro scales, apply
-        # the two-level dequant. The only f32 step left is the final
-        # division by the block scale, whose 23-bit mantissa a bf16
-        # divide could double-round -- same op order as
-        # ref.decode_mixed_ref after the exact-cast steps, so
-        # interpret/xla stay bit-exact.
+        # Unpack row-halved E2M1 nibbles and narrow them to the storage
+        # dtype (grid values and the vals * micro-scale products are
+        # exact in bf16 -- <= 5 significand bits), expand micro scales,
+        # apply the two-level dequant. The nibbles decode in f32: Mosaic
+        # cannot relayout the selects of a bf16 decode of a half-height
+        # nibble block. The only f32 step left is the final division by
+        # the block scale, whose 23-bit mantissa a bf16 divide could
+        # double-round -- same op order as ref.decode_mixed_ref after
+        # the exact-cast steps, so interpret/xla stay bit-exact.
         n32 = nib.astype(jnp.int32)
-        lo = decode_e2m1(n32 & 15, dtype=st_dtype)
-        hi = decode_e2m1(n32 >> 4, dtype=st_dtype)
-        vals = jnp.concatenate([lo, hi], axis=0)  # (br, bk)
+        lo = decode_e2m1(n32 & 15)
+        hi = decode_e2m1(n32 >> 4)
+        vals = jnp.concatenate([lo, hi], axis=0).astype(st_dtype)
         d = jax.lax.bitcast_convert_type(
             ms, jnp.float8_e4m3fn
         ).astype(jnp.float32)
@@ -357,7 +353,7 @@ def mixed_gemm_blocks(
         ),
         out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
         scratch_shapes=scratch_shapes,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=(
                 "parallel",
                 # The A-decode cache is filled at j == 0 and replayed

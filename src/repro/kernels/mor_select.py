@@ -71,7 +71,7 @@ from .ref import expand_micro_onehot
 
 __all__ = ["mor_select_blocks"]
 
-_F32_BIG = 3.4028235e38  # finfo(f32).max: filler for the nonzero-min reduce
+_F32_BIG = 3.4028235e38  # finfo(f32).max
 
 
 def _split_me(s):
@@ -135,7 +135,7 @@ def _kernel(mg_ref, *refs, q_amax4: float, q_amax5: float,
     cnt = jnp.sum(nz.astype(jnp.float32))
 
     def gam_scale(q_amax, m_g):
-        s_b = q_amax / safe_b  # (1, 1)
+        s_b = jnp.minimum(q_amax / safe_b, _F32_BIG)  # core.gam's cap
         m_b, e_b = _split_me(s_b)
         if algo == "gam":
             # Alg. 1 rounding: avoid saturation when m_g > m_b.

@@ -20,7 +20,7 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
-from repro.core.collectives import compat_shard_map, pmax_over
+from repro.core.collectives import pmax_over, shard_map_unchecked
 from repro.core.formats import E4M3, E5M2, NVFP4, NVFP4_MICRO, FormatSpec
 from repro.core.gam import split_mantissa_exponent
 from repro.core.metrics import E5M2_RANGE_RATIO, NVFP4_RANGE_RATIO
@@ -61,18 +61,27 @@ __all__ = [
 
 
 def resolve_backend(backend: str = "auto") -> str:
+    """'auto' -> 'pallas' on TPU, 'interpret' under
+    REPRO_KERNEL_INTERPRET=1, 'xla' otherwise. Interpret mode is a CPU
+    validation mode: asking for it (by name or through the variable)
+    while the default backend is a TPU raises, so kernels never run
+    interpreted on the chip unnoticed."""
+    if backend not in ("auto", "pallas", "interpret", "xla"):
+        raise ValueError(
+            f"unknown backend: {backend!r} "
+            "(want 'auto', 'pallas', 'interpret', or 'xla')"
+        )
+    on_tpu = jax.default_backend() == "tpu"
+    if backend == "auto" and os.environ.get("REPRO_KERNEL_INTERPRET") == "1":
+        backend = "interpret"
+    if backend == "interpret" and on_tpu:
+        raise RuntimeError(
+            "Pallas interpret mode requested on a TPU backend (unset "
+            "REPRO_KERNEL_INTERPRET or pass backend='pallas'/'xla')"
+        )
     if backend != "auto":
-        if backend not in ("pallas", "interpret", "xla"):
-            raise ValueError(
-                f"unknown backend: {backend!r} "
-                "(want 'auto', 'pallas', 'interpret', or 'xla')"
-            )
         return backend
-    if os.environ.get("REPRO_KERNEL_INTERPRET") == "1":
-        return "interpret"
-    if any(d.platform == "tpu" for d in jax.devices()):
-        return "pallas"
-    return "xla"
+    return "pallas" if on_tpu else "xla"
 
 
 def _kernel_backend(backend: str, part: Partition) -> str:
@@ -112,7 +121,9 @@ def _group_mantissa(safe_g: jnp.ndarray, fmt: FormatSpec, algo: str):
     """The Alg. 1 shared mantissa m_g (1.0 for the ablation algos)."""
     if algo != "gam":
         return jnp.float32(1.0)
-    m_g, _ = split_mantissa_exponent(fmt.amax / safe_g)
+    m_g, _ = split_mantissa_exponent(
+        jnp.minimum(fmt.amax / safe_g, jnp.finfo(jnp.float32).max)
+    )
     return m_g
 
 
@@ -591,7 +602,7 @@ def sharded_mixed_gemm(
             out = jax.lax.psum(out, contract_axis)
         return out.astype(out_dtype)
 
-    sm = compat_shard_map(
+    sm = shard_map_unchecked(
         body, mesh,
         in_specs=a_specs + b_specs,
         out_specs=P(row_axis, col_axis),

@@ -1,3 +1,11 @@
-from .mesh import HW, make_local_mesh, make_production_mesh
+from .mesh import (
+    HW,
+    hw_peaks,
+    make_local_mesh,
+    make_mesh,
+    make_production_mesh,
+)
 
-__all__ = ["HW", "make_local_mesh", "make_production_mesh"]
+__all__ = [
+    "HW", "hw_peaks", "make_local_mesh", "make_mesh", "make_production_mesh",
+]

@@ -37,7 +37,7 @@ from repro.configs.base import (
     list_archs,
 )
 from repro.core import BF16_BASELINE, TENSOR_MOR, paper_default
-from repro.launch.mesh import HW, make_production_mesh
+from repro.launch.mesh import hw_peaks, make_production_mesh
 from repro.models import (
     cache_specs,
     init_params,
@@ -295,9 +295,11 @@ def analyze(lowered, compiled, meta, cfg, shape) -> Dict[str, Any]:
     else:
         model_flops = 2 * n_active * shape.global_batch
 
-    compute_s = flops_dev / HW.PEAK_FLOPS_BF16
-    memory_s = bytes_dev / HW.HBM_BW
-    collective_s = coll["total_bytes"] / HW.ICI_BW
+    # The cells model a v5e pod; the compile itself ran on host devices.
+    hw = hw_peaks("TPU v5 lite")
+    compute_s = flops_dev / hw.peak_flops_bf16
+    memory_s = bytes_dev / hw.hbm_bw
+    collective_s = coll["total_bytes"] / hw.ici_bw
     dominant = max(
         ("compute", compute_s), ("memory", memory_s),
         ("collective", collective_s), key=lambda kv: kv[1],
@@ -312,7 +314,7 @@ def analyze(lowered, compiled, meta, cfg, shape) -> Dict[str, Any]:
             "code_bytes": int(mem.generated_code_size_in_bytes),
             "fits_16gb": bool(
                 mem.argument_size_in_bytes + mem.temp_size_in_bytes
-                < HW.HBM_BYTES
+                < hw.hbm_bytes
             ),
         },
         "cost": {

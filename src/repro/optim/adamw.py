@@ -75,7 +75,10 @@ def init_opt_state(
     (repro.optim.moments); ``ef=True`` allocates the error-feedback
     residual tree the '*_ef' gradient-compression modes thread through
     steps."""
-    f32 = lambda p: p.astype(jnp.float32)
+    # copy=True: an f32 param (norm scales) must not share its buffer
+    # with its master copy, or a step donating both params and state
+    # would donate one buffer twice.
+    f32 = lambda p: p.astype(jnp.float32, copy=True)
     zeros = lambda p: jnp.zeros(p.shape, jnp.float32)
 
     def moment(p, kind):

@@ -46,7 +46,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.collectives import all_gather_over
-from repro.core.formats import E4M3
+from repro.core.formats import E4M3, cast_to_format
 from repro.core.mor import (
     EVENT_GRAD,
     STAT_EVENT_KIND,
@@ -91,10 +91,7 @@ def _q_roundtrip(g: jnp.ndarray) -> jnp.ndarray:
     gf = g.astype(jnp.float32)
     amax = jnp.max(jnp.abs(gf))
     scale = jnp.where(amax > 0, E4M3.amax / amax, 1.0)
-    q = jnp.clip(gf * scale, -E4M3.amax, E4M3.amax).astype(
-        jnp.float8_e4m3fn
-    )
-    return (q.astype(jnp.float32) / scale).astype(g.dtype)
+    return (cast_to_format(gf * scale, E4M3) / scale).astype(g.dtype)
 
 
 def _mor_roundtrip(

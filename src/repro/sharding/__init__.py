@@ -1,15 +1,15 @@
 from . import rules
 from .rules import (
-    compat_shard_map,
     mixed_operand_pspec,
     qtensor_pspec_from_dense,
     quantized_param_specs,
+    shard_map_unchecked,
 )
 
 __all__ = [
     "rules",
-    "compat_shard_map",
     "mixed_operand_pspec",
     "qtensor_pspec_from_dense",
     "quantized_param_specs",
+    "shard_map_unchecked",
 ]

@@ -29,7 +29,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ArchConfig
-from repro.core.collectives import compat_shard_map
+from repro.core.collectives import shard_map_unchecked
 from repro.core.formats import NVFP4_MICRO
 from repro.kernels.ref import MixedOperand
 
@@ -38,7 +38,7 @@ __all__ = [
     "named_shardings", "zero1_spec",
     "mixed_operand_pspec", "qtensor_pspec_from_dense",
     "quantized_param_specs", "packed_moment_pspec", "opt_state_specs",
-    "compat_shard_map",
+    "shard_map_unchecked",
 ]
 
 # name-fragment -> (spec builder). Matched against the flattened path.

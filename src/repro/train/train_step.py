@@ -256,6 +256,12 @@ def make_train_step(
             tcfg.optimizer, g_params, opt_state, moments=tcfg.moments,
             guard=tcfg.guard,
         )
+        # Params keep their dtypes (f32 norm scales stay f32): a step
+        # whose outputs differ from its inputs retraces and compiles
+        # again on its second call, and cannot update them in place.
+        new_params = jax.tree.map(
+            lambda n, p: n.astype(p.dtype), new_params, params
+        )
         if "guard_skip" in opt_metrics and new_ef is not None:
             # Skip-step EF preservation: compress_grads already folded
             # this step's residual into `corrected` and re-split it; if

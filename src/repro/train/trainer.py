@@ -69,7 +69,11 @@ class Trainer:
             vocab=cfg.vocab, seq_len=256, global_batch=8,
             seed=run_cfg.seed,
         )
-        self.step_fn = jax.jit(make_train_step(cfg, policy, tcfg))
+        # Donated params and opt state update in place: at full width a
+        # second copy of the training state does not fit the chip.
+        self.step_fn = jax.jit(
+            make_train_step(cfg, policy, tcfg), donate_argnums=(0, 1)
+        )
         self.tracker = MoRStatsTracker()
         self.ckpt = (
             Checkpointer(run_cfg.ckpt_dir, keep=run_cfg.keep)

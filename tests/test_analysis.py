@@ -317,8 +317,6 @@ def test_taint_end_to_end_pack_gemm_decode_chain():
 def test_contract_violation_fires():
     """A contract with unsatisfiable rules reports every miss (and the
     report carries which rule missed)."""
-    from jax.experimental import enable_x64
-
     bad = Contract(
         name="fixture_bad",
         build=lambda: ContractCase(
@@ -328,7 +326,7 @@ def test_contract_violation_fires():
         forbid_f64=True,
         taint=r"\[0\]",  # seed the whole first argument
     )
-    with enable_x64():
+    with jax.enable_x64(True):
         report = check_contract(bad)
     assert not report.ok
     assert any("f64" in v for v in report.violations)
@@ -349,8 +347,6 @@ def test_contract_custom_call_range_fires():
         forbid_f64=False,
     )
     report = check_contract(low)
-    if report.counters.get("tpu_kernel_launches") == -1:
-        pytest.skip("this jax has no cross-platform lowering API")
     assert not report.ok
     assert "custom calls" in report.violations[0]
 

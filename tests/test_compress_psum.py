@@ -155,12 +155,13 @@ def test_pod_psum_bit_identical_to_single_device():
     out = _run_mesh("""
     import jax, jax.numpy as jnp, numpy as np
     from jax.sharding import PartitionSpec as P
-    from repro.core.collectives import compat_shard_map
+    from repro.core.collectives import shard_map_unchecked
     from repro.core.mor import quantize_for_gemm
     from repro.core.policy import MoRPolicy
     from repro.optim.compress import leaf2d, make_pod_compressed_psum
 
-    mesh = jax.make_mesh((2, 2), ('pod', 'data'))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((2, 2), ('pod', 'data'))
     r = np.random.default_rng(0)
     G = r.standard_normal((2, 256, 128)) * np.exp2(
         r.integers(-12, 12, (2, 256, 128)))
@@ -180,7 +181,7 @@ def test_pod_psum_bit_identical_to_single_device():
                     (mo.payload_q[None], mo.tags[None],
                      mo.scales[None]))
         sh = P('pod', 'data', None)
-        out, (pq, tags, scales) = jax.jit(compat_shard_map(
+        out, (pq, tags, scales) = jax.jit(shard_map_unchecked(
             body, mesh, sh, (sh, (sh, sh, sh))))(G)
 
         # Single-device reference: pack each pod's full gradient.

@@ -152,6 +152,11 @@ def test_zero_tensor_scales_are_finite():
     algo=st.sampled_from(["gam", "e8m0"]),
     kind=st.sampled_from(["tensor", "block", "channel"]),
 )
+# The smallest normal f32 amax: 448 / amax overflows f32.
+@hypothesis.example(
+    data=np.full((1, 1), np.finfo(np.float32).tiny, np.float32),
+    algo="gam", kind="tensor",
+)
 def test_property_no_saturation(data, algo, kind):
     part = Partition(kind, (32, 32))
     x = jnp.asarray(data)
@@ -160,3 +165,34 @@ def test_property_no_saturation(data, algo, kind):
     scale = np.asarray(sc.scale, np.float64)
     assert np.all(bmax * scale <= E4M3.amax * (1 + 1e-6))
     assert np.all(np.isfinite(scale)) and np.all(scale > 0)
+
+
+# ------------------------------------------------------------------------
+# fp8 grid snap: bit arithmetic, bit-identical to the saturating cast.
+# ------------------------------------------------------------------------
+@pytest.mark.parametrize("fmt", [E4M3, E5M2], ids=lambda f: f.name)
+def test_round_to_fp8_matches_ml_dtypes(fmt):
+    import ml_dtypes
+
+    from repro.core.formats import cast_to_format
+
+    dt = {"e4m3": ml_dtypes.float8_e4m3fn, "e5m2": ml_dtypes.float8_e5m2}[
+        fmt.name
+    ]
+    rng = np.random.default_rng(0)
+    n = 1 << 16
+    grid = np.arange(256, dtype=np.uint8).view(dt).astype(np.float32)
+    grid = np.sort(grid[np.isfinite(grid)])
+    mids = (grid[1:] + grid[:-1]) / 2  # ties: round half to even
+    x = np.concatenate([
+        np.exp2(rng.uniform(-25, 17, n)).astype(np.float32)
+        * rng.choice([-1.0, 1.0], n).astype(np.float32),
+        grid, mids, np.nextafter(mids, np.inf), np.nextafter(mids, -np.inf),
+        np.array([0.0, -0.0, 1e-45, np.inf, -np.inf, np.nan], np.float32),
+    ])
+    want = np.clip(x, -fmt.amax, fmt.amax).astype(dt).astype(np.float32)
+    got = np.asarray(jax.jit(lambda v: cast_to_format(v, fmt))(x))
+    same = (got.view(np.uint32) == want.view(np.uint32)) | (
+        np.isnan(got) & np.isnan(want)
+    )
+    assert same.all(), x[~same][:8]

@@ -42,7 +42,8 @@ def test_train_step_runs_sharded():
         cfg = dataclasses.replace(reduced(get_config('llama3-8b')),
                                   vocab=256, d_model=64, n_heads=4,
                                   n_kv=2, head_dim=16)
-        mesh = jax.make_mesh((4, 2), ('data', 'model'))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((4, 2), ('data', 'model'))
         with use_mesh(mesh):
             params = init_params(cfg, jax.random.PRNGKey(0))
             pspec = rules.param_specs(cfg, params)
@@ -123,7 +124,8 @@ def test_elastic_remesh_resume():
         cfg = dataclasses.replace(reduced(get_config('llama3-8b')),
                                   vocab=256)
         params = init_params(cfg, jax.random.PRNGKey(0))
-        mesh8 = jax.make_mesh((4, 2), ('data', 'model'))
+        from repro.launch.mesh import make_mesh
+        mesh8 = make_mesh((4, 2), ('data', 'model'))
         pspec = rules.param_specs(cfg, params)
         params8 = jax.tree.map(
             lambda x, s: jax.device_put(x, NamedSharding(mesh8, s)),
@@ -149,7 +151,8 @@ def test_fp8_compressed_pod_psum():
         from jax.sharding import PartitionSpec as P
         from repro.optim.compress import make_pod_compressed_psum
 
-        mesh = jax.make_mesh((2, 4), ('pod', 'data'))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 4), ('pod', 'data'))
         g = jnp.asarray(np.random.RandomState(0).randn(2, 64, 64),
                         jnp.float32)
 
@@ -158,13 +161,8 @@ def test_fp8_compressed_pod_psum():
         def f(gs):
             return psum_fp8(gs[0])
 
-        if hasattr(jax, 'shard_map'):
-            sm = jax.shard_map(f, mesh=mesh, in_specs=P('pod'),
-                               out_specs=P(), check_vma=False)
-        else:  # older jax: experimental API, check_rep kwarg
-            from jax.experimental.shard_map import shard_map
-            sm = shard_map(f, mesh=mesh, in_specs=P('pod'),
-                           out_specs=P(), check_rep=False)
+        sm = jax.shard_map(f, mesh=mesh, in_specs=P('pod'),
+                           out_specs=P(), check_vma=False)
 
         out = jax.jit(sm)(g)
         ref = jnp.sum(g, axis=0)

@@ -27,10 +27,7 @@ def test_scan_matmul_flops_trip_multiplied():
         f"walked={cost.flops:.3e} expected~{expect:.3e}"
     )
     # XLA's own analysis (trip-count-blind) must be well below ours.
-    ca = compiled.cost_analysis()
-    if isinstance(ca, list):  # older jax: one dict per device
-        ca = ca[0]
-    xla = float(ca.get("flops", 0.0))
+    xla = float(compiled.cost_analysis().get("flops", 0.0))
     assert xla < 0.5 * cost.flops
 
 

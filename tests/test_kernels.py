@@ -63,6 +63,11 @@ def test_gam_quant_no_saturation_property():
             x, block=(128, 128), backend="interpret"
         )
         assert np.all(np.isfinite(np.asarray(xq, np.float32)))
+    # amax under E4M3.amax / f32max: the ideal scale overflows f32, and
+    # an uncapped Inf scale turns the zero into 0 * Inf = NaN.
+    x = _rand((256, 256), seed=3, scale=1e-37).at[0, 0].set(0.0)
+    xq = gam_quant(x, block=(128, 128), backend="interpret")[0]
+    assert np.all(np.isfinite(np.asarray(xq, np.float32)))
 
 
 # -------------------------------------------------------------- fp8_gemm --

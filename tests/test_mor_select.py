@@ -10,6 +10,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.analysis.hlo_rules import tpu_lowering_text
 from repro.core.formats import E4M3
 from repro.core.metrics import E5M2_RANGE_RATIO
 from repro.core.partition import Partition
@@ -131,16 +132,6 @@ def test_smooth_gaussian_selects_e4m3():
 
 
 # ------------------------------------------------- TPU lowerability ----
-def _tpu_lowering_text(fn, *args):
-    import jax
-
-    try:
-        traced = jax.jit(fn).trace(*args)
-        return traced.lower(lowering_platforms=("tpu",)).as_text()
-    except TypeError:
-        pytest.skip("this jax has no cross-platform lowering API")
-
-
 def test_mor_select_kernel_lowers_for_tpu():
     """Mosaic-lowerable on a CPU host: catches VMEM-scalar-store /
     scalar-bitcast / (1,1)-block-tiling regressions without hardware."""
@@ -157,7 +148,7 @@ def test_mor_select_kernel_lowers_for_tpu():
             a, jnp.stack([m4, m5]), block=(128, 128), mode="sub3"
         )[0]
 
-    txt = _tpu_lowering_text(f, x)
+    txt = tpu_lowering_text(f, x)
     assert txt.count("tpu_custom_call") == 1
 
 
@@ -172,7 +163,7 @@ def test_gam_quant_kernel_lowers_for_tpu():
         m, _ = split_mantissa_exponent(E4M3.amax / g)
         return gam_quant_blocks(a, m, block=(128, 128))[0]
 
-    txt = _tpu_lowering_text(f, x)
+    txt = tpu_lowering_text(f, x)
     assert txt.count("tpu_custom_call") == 1
 
 

@@ -20,6 +20,8 @@ from repro.core.mor import quantize_for_gemm
 
 from test_nvfp4 import _nvfp4_friendly
 
+_F32_1E30 = float(np.float32(1e30))
+
 
 @hypothesis.settings(deadline=None, max_examples=20)
 @hypothesis.given(
@@ -45,8 +47,9 @@ def test_property_pack_roundtrip(m, k, seed, span, algo):
 @hypothesis.settings(deadline=None, max_examples=15)
 @hypothesis.given(
     data=st.lists(
-        st.floats(min_value=-1e30, max_value=1e30, allow_nan=False,
-                  width=32),
+        # Bounds must be float32-exact for width=32: 1e30 rounded to f32.
+        st.floats(min_value=-_F32_1E30, max_value=_F32_1E30,
+                  allow_nan=False, width=32),
         min_size=1, max_size=64,
     )
 )

@@ -191,13 +191,6 @@ def test_pack_has_nvfp4_hint():
 
 
 # ------------------------------------------------------- HLO contract --
-def _tpu_lowering_text(fn, *args):
-    try:
-        return hlo_rules.tpu_lowering_text(fn, *args)
-    except hlo_rules.CrossLoweringUnavailable:
-        pytest.skip("this jax has no cross-platform lowering API")
-
-
 @pytest.mark.parametrize("recipe", ("sub3", "sub4"))
 def test_pack_single_launch_no_xla_pack_pass(recipe):
     """quantize_for_gemm on the pallas backend is one tpu_custom_call,
@@ -207,8 +200,6 @@ def test_pack_single_launch_no_xla_pack_pass(recipe):
     contract registry -- this test, bench_kernels and CI's lint job
     all evaluate the same ``quantize_pack_*`` contract."""
     report = contracts.check(f"quantize_pack_{recipe}")
-    if report.counters.get("tpu_kernel_launches") == -1:
-        pytest.skip("this jax has no cross-platform lowering API")
     assert report.ok, report.render()
 
     # The two-pass oracle really is a multi-pass XLA program (sanity
@@ -228,8 +219,8 @@ def test_pack_single_launch_no_xla_pack_pass(recipe):
             a, part, recipe, "gam", backend="pallas"
         ).y
 
-    legacy_txt = _tpu_lowering_text(two_pass, x)
-    sel_txt = _tpu_lowering_text(select_only, x)
+    legacy_txt = hlo_rules.tpu_lowering_text(two_pass, x)
+    sel_txt = hlo_rules.tpu_lowering_text(select_only, x)
     assert (hlo_rules.operand_sized_ops(legacy_txt, x.shape)
             > hlo_rules.operand_sized_ops(sel_txt, x.shape))
 
@@ -292,7 +283,7 @@ def test_pack_kernel_mosaic_lowers():
             a, jnp.ones((3,), jnp.float32), jnp.float32(1.0),
             mode=mode, emit="pack",
         )
-        txt = _tpu_lowering_text(f, x)
+        txt = hlo_rules.tpu_lowering_text(f, x)
         assert hlo_rules.count_custom_calls(txt) == 1, mode
 
 
@@ -320,9 +311,10 @@ def test_sharded_fused_pack_invariance():
     from jax.sharding import PartitionSpec as P
     from repro.core.policy import MoRPolicy
     from repro.core.mor import quantize_for_gemm
-    from repro.core.collectives import compat_shard_map
+    from repro.core.collectives import shard_map_unchecked
 
-    mesh = jax.make_mesh((4,), ('data',))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((4,), ('data',))
     r = np.random.default_rng(0)
     base = r.standard_normal((256, 128)) * np.exp2(
         r.integers(-12, 12, (256, 128)))
@@ -340,7 +332,7 @@ def test_sharded_fused_pack_invariance():
                 return (mo.payload_q, mo.payload_bf16, mo.payload_nib,
                         mo.micro_scales, mo.tags, mo.scales), s
             sh = P('data', None)
-            (pq, pbf, nib, ms, t, sc), s2 = jax.jit(compat_shard_map(
+            (pq, pbf, nib, ms, t, sc), s2 = jax.jit(shard_map_unchecked(
                 gbody, mesh, P('data', None),
                 ((sh, sh, sh, sh, sh, sh), P())))(x)
             np.testing.assert_array_equal(np.asarray(mo1.tags),

@@ -48,10 +48,11 @@ _PRELUDE = f"""
     from repro.core.policy import MoRPolicy, MoRDotPolicy, with_mesh_axes
     from repro.core.mor import mor_quantize, quantize_for_gemm
     from repro.core.linear import mor_dot, new_token
-    from repro.core.collectives import compat_shard_map
+    from repro.core.collectives import shard_map_unchecked
     from repro.kernels import ops as kops
 
-    mesh = jax.make_mesh((4,), ('data',))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((4,), ('data',))
     EXACT = {EXACT_COLS}
 
     def check_stats(s1, s2):
@@ -86,7 +87,7 @@ def test_quantize_invariance_all_recipes():
         def body(a):
             y, s = mor_quantize(a, pol_sh)
             return y, s
-        y2, s2 = jax.jit(compat_shard_map(
+        y2, s2 = jax.jit(shard_map_unchecked(
             body, mesh, P('data', None), (P('data', None), P())))(x)
         np.testing.assert_array_equal(
             np.asarray(y1, np.float32), np.asarray(y2, np.float32))
@@ -104,7 +105,7 @@ def test_quantize_invariance_all_recipes():
             return (mo.payload_q, mo.payload_bf16, mo.payload_nib,
                     mo.micro_scales, mo.tags, mo.scales), s
         sh = P('data', None)
-        (pq2, pb2, nib2, ms2, t2, sc2), _ = jax.jit(compat_shard_map(
+        (pq2, pb2, nib2, ms2, t2, sc2), _ = jax.jit(shard_map_unchecked(
             gbody, mesh, P('data', None),
             ((sh, sh, sh, sh, sh, sh), P())))(x)
         np.testing.assert_array_equal(np.asarray(mo1.tags), np.asarray(t2))
@@ -162,7 +163,7 @@ def test_mor_dot_invariance_fused_and_fake():
             def body(a, d, b):
                 y, st, dx, dw, dtok = run(a, b, d, dp_sh)
                 return y, st, dx, jax.lax.psum(dw, 'data'), dtok
-            sm = compat_shard_map(
+            sm = shard_map_unchecked(
                 body, mesh,
                 in_specs=(P('data', None), P('data', None),
                           P(None, None)),

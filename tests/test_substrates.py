@@ -138,6 +138,34 @@ def test_trainer_restart_resumes_bitexact(tmp_path):
     np.testing.assert_allclose(l_resumed, l_straight, rtol=5e-4)
 
 
+def test_trainer_donated_state_resumes_bitexact(tmp_path):
+    """The step donates params and opt state (updated in place), keeps
+    every param dtype, and a restart restores the state a donating run
+    checkpointed bit for bit."""
+    tr = _tiny_trainer(tmp_path / "e", total_steps=6, ckpt_every=3)
+    params = init_params(tr.cfg, jax.random.PRNGKey(0))
+    opt = init_opt_state(params)
+    batch = jax.tree.map(
+        jnp.asarray, SyntheticLM(tr.data_cfg).batch_at(0)
+    )
+    new_params, new_opt, _ = tr.step_fn(params, opt, batch)
+    old = jax.tree.leaves((params, opt))
+    assert all(leaf.is_deleted() for leaf in old)
+    assert jax.tree.map(lambda a: a.dtype, new_params) == jax.tree.map(
+        lambda a: a.dtype, params
+    )
+
+    r1 = tr.run()
+    r2 = _tiny_trainer(tmp_path / "e", total_steps=6, ckpt_every=3).run()
+    assert r2["history"] == []  # resumed at the last step: nothing to run
+    for a, b in zip(
+        jax.tree.leaves((r1["params"], r1["opt_state"])),
+        jax.tree.leaves((r2["params"], r2["opt_state"])),
+    ):
+        assert a.dtype == b.dtype
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
 def test_trainer_straggler_watchdog(tmp_path):
     hits = []
     tr = _tiny_trainer(tmp_path / "d", total_steps=12)

@@ -366,11 +366,12 @@ def test_packed_moment_mesh_bit_identity():
     out = _run_mesh("""
     import jax, jax.numpy as jnp, numpy as np
     from jax.sharding import PartitionSpec as P
-    from repro.core.collectives import compat_shard_map
+    from repro.core.collectives import shard_map_unchecked
     from repro.core.policy import MoRPolicy
     from repro.optim.moments import encode_moment
 
-    mesh = jax.make_mesh((4,), ('data',))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((4,), ('data',))
     r = np.random.default_rng(0)
     base = r.standard_normal((512, 128)) * np.exp2(
         r.integers(-12, 12, (512, 128)))
@@ -389,7 +390,7 @@ def test_packed_moment_mesh_bit_identity():
             return (mo.payload_q, mo.payload_bf16, mo.payload_nib,
                     mo.micro_scales, mo.tags, mo.scales), pm.stats
         sh = P('data', None)
-        lanes, s2 = jax.jit(compat_shard_map(
+        lanes, s2 = jax.jit(shard_map_unchecked(
             body, mesh, P('data', None),
             ((sh, sh, sh, sh, sh, sh), P())))(x)
         mo1 = pm1.mo

@@ -1,5 +1,5 @@
 """Benchmark orchestrator: one section per paper table/figure + kernel
-microbench + roofline. Prints ``name,us_per_call,derived`` CSV."""
+microbench. Prints ``name,us_per_call,derived`` CSV."""
 from __future__ import annotations
 
 import argparse
@@ -22,7 +22,6 @@ def main() -> None:
         bench_table2,
         bench_table3,
         bench_table4,
-        roofline,
     )
 
     sections = {
@@ -32,7 +31,6 @@ def main() -> None:
         "fig10": lambda: bench_fig10.main(steps=max(args.steps // 2, 30)),
         "fig11": lambda: bench_fig11.main(steps=max(args.steps // 3, 20)),
         "kernels": bench_kernels.main,
-        "roofline": roofline.main,
     }
     chosen = (
         {k: sections[k] for k in args.only.split(",")}

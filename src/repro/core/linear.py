@@ -32,6 +32,12 @@ GEMM lowerings (``MoRDotPolicy.fuse_gemm``):
     rows (one shared decision path), outputs within f32-accumulation
     ordering tolerance.
 
+Named scopes: each quantization event runs under ``mor_quant/<role>``
+(``fwd_x``, ``fwd_w``, ``dgrad_dy``, ``dgrad_w``, ``wgrad_x``,
+``wgrad_dy``) and each product under ``gemm/<which>`` (``fwd``,
+``dgrad``, ``wgrad``), siblings under the caller's scope, so a device
+trace can tell quantization time from matmul time per sublayer.
+
 Serving: a weight that is already real-quantized (``serve.quantized
 .QTensor``; anything exposing ``as_mixed_operand()``) is consumed
 directly by the mixed kernel against a BF16-passthrough activation
@@ -39,6 +45,7 @@ pack -- no dequantize-materialize step, no grad support.
 """
 from __future__ import annotations
 
+import contextlib
 import functools
 from typing import Tuple
 
@@ -66,6 +73,12 @@ N_BWD_EVENTS = 4  # dy(dgrad), w(dgrad), x^T(wgrad), dy^T(wgrad)
 def new_token() -> jnp.ndarray:
     """Zero token whose cotangent carries the N_BWD_EVENTS stats rows."""
     return jnp.zeros((N_BWD_EVENTS, STATS_WIDTH), dtype=jnp.float32)
+
+
+@contextlib.contextmanager
+def _scope(layer: str, part: str):
+    with jax.named_scope(layer), jax.named_scope(part):
+        yield
 
 
 def _flat2d(x: jnp.ndarray) -> Tuple[jnp.ndarray, Tuple[int, ...]]:
@@ -148,9 +161,10 @@ def _serve_fwd(x, w, policy: MoRDotPolicy):
     """Forward against a real-quantized (mixed-layout) serving weight."""
     mo = w.as_mixed_operand()  # (N, K) quantization view
     x2, lead = _flat2d(x)
-    y = kops.mixed_dot(
-        x2, mo, out_dtype=x.dtype, backend=policy.weight.backend
-    ).reshape(*lead, w.shape[1])
+    with _scope("gemm", "fwd"):
+        y = kops.mixed_dot(
+            x2, mo, out_dtype=x.dtype, backend=policy.weight.backend
+        ).reshape(*lead, w.shape[1])
     fwd_stats = jnp.zeros((N_FWD_EVENTS, STATS_WIDTH), jnp.float32)
     return (y, fwd_stats), (x, w)
 
@@ -160,7 +174,8 @@ def _fwd(x, w, token, policy: MoRDotPolicy):
     if _is_mixed_weight(w):
         return _serve_fwd(x, w, policy)
     if not policy.enabled:
-        y = _plain_dot(x, w)
+        with _scope("gemm", "fwd"):
+            y = _plain_dot(x, w)
         fwd_stats = jnp.zeros((N_FWD_EVENTS, STATS_WIDTH), jnp.float32)
         return (y, fwd_stats), (x, w)
 
@@ -169,23 +184,30 @@ def _fwd(x, w, token, policy: MoRDotPolicy):
         _check_fusable(policy)
         # Activation event (M, K) and weight event (N, K): both packed
         # for real, contraction last; the kernel consumes the payloads.
-        a_mo, x_stats = quantize_for_gemm(x2, policy.act)
-        b_mo, w_stats = quantize_for_gemm(w.T, policy.weight)
-        y = kops.mixed_gemm(
-            a_mo, b_mo, out_dtype=x.dtype, backend=policy.act.backend
-        )
+        with _scope("mor_quant", "fwd_x"):
+            a_mo, x_stats = quantize_for_gemm(x2, policy.act)
+        with _scope("mor_quant", "fwd_w"):
+            b_mo, w_stats = quantize_for_gemm(w.T, policy.weight)
+        with _scope("gemm", "fwd"):
+            y = kops.mixed_gemm(
+                a_mo, b_mo, out_dtype=x.dtype, backend=policy.act.backend
+            )
     else:
         # Activation event: (M, K), contraction last.
-        xq, x_stats = mor_quantize(x2, policy.act)
+        with _scope("mor_quant", "fwd_x"):
+            xq, x_stats = mor_quantize(x2, policy.act)
         # Weight event for the fwd GEMM: w is (K, N), contraction first ->
         # quantize the (N, K) transposed view so channels align with the
         # dot dim.
-        wq_t, w_stats = mor_quantize(w.T, policy.weight)
-        y = jnp.dot(
-            xq, wq_t.T, preferred_element_type=jnp.float32
-        ).astype(x.dtype)
+        with _scope("mor_quant", "fwd_w"):
+            wq_t, w_stats = mor_quantize(w.T, policy.weight)
+        with _scope("gemm", "fwd"):
+            y = jnp.dot(
+                xq, wq_t.T, preferred_element_type=jnp.float32
+            ).astype(x.dtype)
     y = y.reshape(*lead, w.shape[1])
-    fwd_stats = jnp.stack([x_stats, w_stats])
+    with jax.named_scope("mor_quant"):
+        fwd_stats = jnp.stack([x_stats, w_stats])
     return (y, fwd_stats), (x, w)
 
 
@@ -215,30 +237,39 @@ def _bwd_fused(policy: MoRDotPolicy, x2, dy2, lead, x, w):
     be = policy.grad.backend
     # dgrad GEMM: dx[m,k] = sum_n dy[m,n] * w[k,n] -- both views
     # contraction-last already.
-    dy_mo, dy_stats = quantize_for_gemm(dy2, policy.grad)      # (M, N)
-    w_mo, w_stats = quantize_for_gemm(w, policy.weight)        # (K, N)
-    dx = kops.mixed_gemm(
-        dy_mo, w_mo, out_dtype=x.dtype, backend=be
-    ).reshape(*lead, x.shape[-1])
+    with _scope("mor_quant", "dgrad_dy"):
+        dy_mo, dy_stats = quantize_for_gemm(dy2, policy.grad)  # (M, N)
+    with _scope("mor_quant", "dgrad_w"):
+        w_mo, w_stats = quantize_for_gemm(w, policy.weight)    # (K, N)
+    with _scope("gemm", "dgrad"):
+        dx = kops.mixed_gemm(
+            dy_mo, w_mo, out_dtype=x.dtype, backend=be
+        ).reshape(*lead, x.shape[-1])
 
     # wgrad GEMM: dw[k,n] = sum_m x[m,k] * dy[m,n].
     if _transpose_invariant(policy.act) and _transpose_invariant(policy.grad):
         # Q(x^T) == Q(x)^T bit-exactly: pack the (M, K) view and
         # transpose the pack (tags/scales/payloads permute with the
         # blocks), reusing the dy pack outright.
-        x_mo, xT_stats = quantize_for_gemm(x2, policy.act)
-        dw = kops.mixed_gemm(
-            x_mo.transpose(), dy_mo.transpose(),
-            out_dtype=w.dtype, backend=be,
-        )
+        with _scope("mor_quant", "wgrad_x"):
+            x_mo, xT_stats = quantize_for_gemm(x2, policy.act)
+        with _scope("gemm", "wgrad"):
+            dw = kops.mixed_gemm(
+                x_mo.transpose(), dy_mo.transpose(),
+                out_dtype=w.dtype, backend=be,
+            )
         dyT_stats = dy_stats
     else:
-        xT_mo, xT_stats = quantize_for_gemm(x2.T, policy.act)    # (K, M)
-        dyT_mo, dyT_stats = quantize_for_gemm(dy2.T, policy.grad)  # (N, M)
-        dw = kops.mixed_gemm(
-            xT_mo, dyT_mo, out_dtype=w.dtype, backend=be
-        )
-    token_grad = jnp.stack([dy_stats, w_stats, xT_stats, dyT_stats])
+        with _scope("mor_quant", "wgrad_x"):
+            xT_mo, xT_stats = quantize_for_gemm(x2.T, policy.act)  # (K, M)
+        with _scope("mor_quant", "wgrad_dy"):
+            dyT_mo, dyT_stats = quantize_for_gemm(dy2.T, policy.grad)
+        with _scope("gemm", "wgrad"):
+            dw = kops.mixed_gemm(
+                xT_mo, dyT_mo, out_dtype=w.dtype, backend=be
+            )
+    with jax.named_scope("mor_quant"):
+        token_grad = jnp.stack([dy_stats, w_stats, xT_stats, dyT_stats])
     return dx, dw, token_grad
 
 
@@ -254,12 +285,14 @@ def _bwd(policy: MoRDotPolicy, res, cts):
     x2, lead = _flat2d(x)
 
     if not (policy.enabled and policy.quantize_bwd):
-        dx = jnp.dot(
-            dy2, w.T, preferred_element_type=jnp.float32
-        ).astype(x.dtype).reshape(x.shape)
-        dw = jnp.dot(
-            x2.T, dy2, preferred_element_type=jnp.float32
-        ).astype(w.dtype)
+        with _scope("gemm", "dgrad"):
+            dx = jnp.dot(
+                dy2, w.T, preferred_element_type=jnp.float32
+            ).astype(x.dtype).reshape(x.shape)
+        with _scope("gemm", "wgrad"):
+            dw = jnp.dot(
+                x2.T, dy2, preferred_element_type=jnp.float32
+            ).astype(w.dtype)
         return dx, dw, jnp.zeros((N_BWD_EVENTS, STATS_WIDTH), jnp.float32)
 
     if policy.fuse_gemm:
@@ -267,31 +300,40 @@ def _bwd(policy: MoRDotPolicy, res, cts):
         return _bwd_fused(policy, x2, dy2, lead, x, w)
 
     # dgrad GEMM: dx[m,k] = sum_n dy[m,n] * w[k,n].
-    dyq, dy_stats = mor_quantize(dy2, policy.grad)          # (M, N) contr. n
-    w_kn, w_stats = mor_quantize(w, policy.weight)          # (K, N) contr. n
-    dx = jnp.dot(
-        dyq, w_kn.T, preferred_element_type=jnp.float32
-    ).astype(x.dtype).reshape(*lead, x.shape[-1])
+    with _scope("mor_quant", "dgrad_dy"):
+        dyq, dy_stats = mor_quantize(dy2, policy.grad)      # (M, N) contr. n
+    with _scope("mor_quant", "dgrad_w"):
+        w_kn, w_stats = mor_quantize(w, policy.weight)      # (K, N) contr. n
+    with _scope("gemm", "dgrad"):
+        dx = jnp.dot(
+            dyq, w_kn.T, preferred_element_type=jnp.float32
+        ).astype(x.dtype).reshape(*lead, x.shape[-1])
 
     # wgrad GEMM: dw[k,n] = sum_m x[m,k] * dy[m,n].
     # For transpose-invariant partitions, Q(x^T) == Q(x)^T bit-exactly, so
     # re-quantizing along M re-uses the same quantized values (avoids two
     # extra full-tensor quantization passes; Perf iteration 2).
     if _transpose_invariant(policy.act) and _transpose_invariant(policy.grad):
-        xTq, xT_stats = mor_quantize(x2, policy.act)
+        with _scope("mor_quant", "wgrad_x"):
+            xTq, xT_stats = mor_quantize(x2, policy.act)
         dyTq, dyT_stats = dyq, dy_stats  # Q(dy^T) == Q(dy)^T: reuse
-        dw = jax.lax.dot_general(
-            xTq, dyTq, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ).astype(w.dtype)
+        with _scope("gemm", "wgrad"):
+            dw = jax.lax.dot_general(
+                xTq, dyTq, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            ).astype(w.dtype)
     else:
-        xTq, xT_stats = mor_quantize(x2.T, policy.act)      # (K, M) contr. m
-        dyTq, dyT_stats = mor_quantize(dy2.T, policy.grad)  # (N, M) contr. m
-        dw = jnp.dot(
-            xTq, dyTq.T, preferred_element_type=jnp.float32
-        ).astype(w.dtype)
+        with _scope("mor_quant", "wgrad_x"):
+            xTq, xT_stats = mor_quantize(x2.T, policy.act)  # (K, M) contr. m
+        with _scope("mor_quant", "wgrad_dy"):
+            dyTq, dyT_stats = mor_quantize(dy2.T, policy.grad)  # (N, M)
+        with _scope("gemm", "wgrad"):
+            dw = jnp.dot(
+                xTq, dyTq.T, preferred_element_type=jnp.float32
+            ).astype(w.dtype)
 
-    token_grad = jnp.stack([dy_stats, w_stats, xT_stats, dyT_stats])
+    with jax.named_scope("mor_quant"):
+        token_grad = jnp.stack([dy_stats, w_stats, xT_stats, dyT_stats])
     return dx, dw, token_grad
 
 

@@ -186,4 +186,5 @@ def flash_attention_fwd(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,
+        name="flash_attention_fwd",
     )(q, k, v, off)

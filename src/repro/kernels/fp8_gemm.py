@@ -90,4 +90,5 @@ def fp8_gemm(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,
+        name="fp8_gemm",
     )(a_q, b_q, a_scale, b_scale)

@@ -141,4 +141,5 @@ def gam_quant_blocks(
         ],
         out_shape=out_shapes,
         interpret=interpret,
+        name="gam_quant_blocks",
     )(mg, x)

@@ -363,5 +363,6 @@ def mixed_gemm_blocks(
             )
         ),
         interpret=interpret,
+        name="mixed_gemm_blocks",
     )(a_tags, a_scales, b_tags, b_scales,
       a_q, a_bf, a_nib, a_ms, b_q, b_bf, b_nib, b_ms)

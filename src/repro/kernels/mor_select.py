@@ -429,4 +429,5 @@ def mor_select_blocks(
         out_specs=out_specs,
         out_shape=tuple(out_shapes),
         interpret=interpret,
+        name="mor_select_blocks",
     )(*operands)

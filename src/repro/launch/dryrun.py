@@ -82,7 +82,7 @@ def collective_bytes_from_hlo(hlo: str) -> Dict[str, Any]:
     """Sum operand bytes of collective ops in the partitioned HLO.
 
     Shapes in the partitioned module are per-device, so the totals here
-    are per-device traffic per step (see benchmarks/roofline.py).
+    are per-device traffic per step.
     """
     per_op = {c: 0 for c in _COLLECTIVES}
     counts = {c: 0 for c in _COLLECTIVES}

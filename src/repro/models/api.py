@@ -66,13 +66,14 @@ def make_loss_fn(cfg: ArchConfig, policy: MoRDotPolicy, *,
         logits, _, stats = T.forward(
             cfg, policy, params, tokens, batch, mode="train", remat=remat
         )
-        labels = batch["labels"]
-        if cfg.family == "vlm":
-            # Labels cover text positions only; drop image-prefix logits.
-            logits = logits[:, cfg.img_tokens :]
-        loss = cross_entropy(logits, labels)
-        aux_loss = _collect_aux_losses(stats)
-        total = loss + aux_coef * aux_loss
+        with jax.named_scope("loss"):
+            labels = batch["labels"]
+            if cfg.family == "vlm":
+                # Labels cover text positions only; drop image-prefix logits.
+                logits = logits[:, cfg.img_tokens :]
+            loss = cross_entropy(logits, labels)
+            aux_loss = _collect_aux_losses(stats)
+            total = loss + aux_coef * aux_loss
         return total, {"loss": loss, "aux_loss": aux_loss, "mor_fwd": stats}
 
     return loss_fn

@@ -4,6 +4,11 @@ Every GEMM the paper quantizes (linear_qkv, linear_proj, fc1, fc2, and the
 MoE expert FFNs) goes through :func:`repro.core.mor_dot`; routers, norms and
 embeddings stay BF16, matching the paper's policy.
 
+Named scopes mark the layers in the compiled program: ``norm``,
+``residual``, ``attn/{qkv,rope,core,proj}`` and ``mlp/{fc1,act,fc2}``
+here, ``mor_quant/<role>`` and ``gemm/<which>`` inside each linear
+(``repro.core.linear``), so a device trace can be read per layer.
+
 Block functions share the signature
     f(p, x, tok, policy, cfg, mode, cache, cur_index) -> (x, cache, stats)
 where ``p``/``tok``/``cache`` are this layer's slices of the stacked
@@ -31,15 +36,24 @@ from .common import (
 )
 
 __all__ = [
-    "norm", "attn_sublayer", "mlp_sublayer", "moe_sublayer",
+    "norm", "residual", "attn_sublayer", "mlp_sublayer", "moe_sublayer",
     "dense_block", "moe_block",
 ]
 
 
+@jax.named_scope("norm")
 def norm(p_norm, x, cfg: ArchConfig):
     if cfg.norm == "ln":
         return layer_norm(x, p_norm["scale"], p_norm["bias"])
     return rms_norm(x, p_norm["scale"])
+
+
+@jax.named_scope("residual")
+def residual(x, *branches):
+    """The residual stream plus each sublayer output, in order."""
+    for b in branches:
+        x = x + b
+    return x
 
 
 def _split_qkv(qkv, cfg: ArchConfig):
@@ -53,6 +67,7 @@ def _split_qkv(qkv, cfg: ArchConfig):
     )
 
 
+@jax.named_scope("attn")
 def attn_sublayer(
     p,
     xn,
@@ -70,13 +85,14 @@ def attn_sublayer(
 ):
     """Self-attention with GQA + RoPE + KV cache. Returns (y, cache, stats)."""
     B, S, _ = xn.shape
-    qkv, st_qkv = mor_dot(xn, p["wqkv"], tok["qkv"], policy)
-    # Pin the SP->TP transition on the BF16 GEMM output: without this
-    # GSPMD reshards f32 rope/quant intermediates (2x collective bytes,
-    # Perf iteration 5).
-    if mode != "decode" and S > 1:
-        qkv = constrain(qkv, "batch", None, "model")
-    q, k, v = _split_qkv(qkv, cfg)
+    with jax.named_scope("qkv"):
+        qkv, st_qkv = mor_dot(xn, p["wqkv"], tok["qkv"], policy)
+        # Pin the SP->TP transition on the BF16 GEMM output: without
+        # this GSPMD reshards f32 rope/quant intermediates (2x
+        # collective bytes, Perf iteration 5).
+        if mode != "decode" and S > 1:
+            qkv = constrain(qkv, "batch", None, "model")
+        q, k, v = _split_qkv(qkv, cfg)
 
     if mode == "decode":
         # cur_index is the position of the LAST query token: a scalar
@@ -90,83 +106,100 @@ def attn_sublayer(
         )
         pos = cur[:, None] - (S - 1) + jnp.arange(S, dtype=jnp.int32)[None]
         if use_rope:
-            q = apply_rope(q, pos, cfg.rope_theta)
-            k = apply_rope(k, pos, cfg.rope_theta)
-        rows = jnp.arange(B, dtype=jnp.int32)[:, None]
-        upd = lambda buf, val: buf.at[rows, pos].set(val.astype(buf.dtype))
-        mor_cache = "k_tags" in cache
-        fp8_cache = (not mor_cache) and "k_scale" in cache
-        if mor_cache:
-            # MoR cache tier: per-(position, head) tag-select between
-            # the fp8 arms + GAM scales (docs/numerics.md); decode
-            # folds the scales into score space per tag.
-            from .attention import quantize_kv_mor
-
-            k_pay, k_t, k_s = quantize_kv_mor(k)
-            v_pay, v_t, v_s = quantize_kv_mor(v)
-            new_cache = {
-                "k": upd(cache["k"], k_pay),
-                "v": upd(cache["v"], v_pay),
-                "k_tags": upd(cache["k_tags"], k_t),
-                "v_tags": upd(cache["v_tags"], v_t),
-                "k_scale": upd(cache["k_scale"], k_s),
-                "v_scale": upd(cache["v_scale"], v_s),
-            }
-            out = decode_attention(
-                q, new_cache["k"], new_cache["v"], cur,
-                window=window, k_scale=new_cache["k_scale"],
-                v_scale=new_cache["v_scale"],
-                k_tags=new_cache["k_tags"], v_tags=new_cache["v_tags"],
-            )
-        elif fp8_cache:
-            from .attention import quantize_kv
-
-            k_pay, k_s = quantize_kv(k)
-            v_pay, v_s = quantize_kv(v)
-            new_cache = {
-                "k": upd(cache["k"], k_pay),
-                "v": upd(cache["v"], v_pay),
-                "k_scale": upd(cache["k_scale"], k_s),
-                "v_scale": upd(cache["v_scale"], v_s),
-            }
-            out = decode_attention(
-                q, new_cache["k"], new_cache["v"], cur,
-                window=window, k_scale=new_cache["k_scale"],
-                v_scale=new_cache["v_scale"],
-            )
-        else:
-            k_cache = upd(cache["k"], k)
-            v_cache = upd(cache["v"], v)
-            out = decode_attention(
-                q, k_cache, v_cache, cur, window=window
-            )
-            new_cache = {"k": k_cache, "v": v_cache}
+            q, k = _rope(q, k, pos, cfg.rope_theta)
+        out, new_cache = _decode_core(q, k, v, cache, cur, pos, window)
     else:
         pos = jnp.arange(S, dtype=jnp.int32)[None]
         if use_rope:
-            q = apply_rope(q, pos, cfg.rope_theta)
-            k = apply_rope(k, pos, cfg.rope_theta)
-        out = flash_attention(
-            q, k, v, kind=kind, prefix_len=prefix_len, window=window
-        )
+            q, k = _rope(q, k, pos, cfg.rope_theta)
+        with jax.named_scope("core"):
+            out = flash_attention(
+                q, k, v, kind=kind, prefix_len=prefix_len, window=window
+            )
         new_cache = (
             {"k": k.astype(jnp.bfloat16), "v": v.astype(jnp.bfloat16)}
             if mode == "prefill"
             else None
         )
 
-    out = out.reshape(B, S, cfg.n_heads * cfg.head_dim)
-    y, st_proj = mor_dot(out, p["wo"], tok["proj"], policy)
+    with jax.named_scope("proj"):
+        out = out.reshape(B, S, cfg.n_heads * cfg.head_dim)
+        y, st_proj = mor_dot(out, p["wo"], tok["proj"], policy)
     return y, new_cache, {"qkv": st_qkv, "proj": st_proj}
 
 
+@jax.named_scope("rope")
+def _rope(q, k, pos, theta: float):
+    return apply_rope(q, pos, theta), apply_rope(k, pos, theta)
+
+
+@jax.named_scope("core")
+def _decode_core(q, k, v, cache, cur, pos, window: int):
+    """Writes the incoming keys and values into the cache at ``pos`` and
+    attends against it. Returns (out, new cache)."""
+    rows = jnp.arange(q.shape[0], dtype=jnp.int32)[:, None]
+    upd = lambda buf, val: buf.at[rows, pos].set(val.astype(buf.dtype))
+    mor_cache = "k_tags" in cache
+    fp8_cache = (not mor_cache) and "k_scale" in cache
+    if mor_cache:
+        # MoR cache tier: per-(position, head) tag-select between
+        # the fp8 arms + GAM scales (docs/numerics.md); decode
+        # folds the scales into score space per tag.
+        from .attention import quantize_kv_mor
+
+        k_pay, k_t, k_s = quantize_kv_mor(k)
+        v_pay, v_t, v_s = quantize_kv_mor(v)
+        new_cache = {
+            "k": upd(cache["k"], k_pay),
+            "v": upd(cache["v"], v_pay),
+            "k_tags": upd(cache["k_tags"], k_t),
+            "v_tags": upd(cache["v_tags"], v_t),
+            "k_scale": upd(cache["k_scale"], k_s),
+            "v_scale": upd(cache["v_scale"], v_s),
+        }
+        out = decode_attention(
+            q, new_cache["k"], new_cache["v"], cur,
+            window=window, k_scale=new_cache["k_scale"],
+            v_scale=new_cache["v_scale"],
+            k_tags=new_cache["k_tags"], v_tags=new_cache["v_tags"],
+        )
+    elif fp8_cache:
+        from .attention import quantize_kv
+
+        k_pay, k_s = quantize_kv(k)
+        v_pay, v_s = quantize_kv(v)
+        new_cache = {
+            "k": upd(cache["k"], k_pay),
+            "v": upd(cache["v"], v_pay),
+            "k_scale": upd(cache["k_scale"], k_s),
+            "v_scale": upd(cache["v_scale"], v_s),
+        }
+        out = decode_attention(
+            q, new_cache["k"], new_cache["v"], cur,
+            window=window, k_scale=new_cache["k_scale"],
+            v_scale=new_cache["v_scale"],
+        )
+    else:
+        k_cache = upd(cache["k"], k)
+        v_cache = upd(cache["v"], v)
+        out = decode_attention(
+            q, k_cache, v_cache, cur, window=window
+        )
+        new_cache = {"k": k_cache, "v": v_cache}
+    return out, new_cache
+
+
+@jax.named_scope("mlp")
 def mlp_sublayer(p, xn, tok, policy: MoRDotPolicy, cfg: ArchConfig,
                  d_ff: Optional[int] = None):
     gated = cfg.act in ("swiglu", "geglu")
     act_fn = activation(cfg.act)
-    h, st1 = mor_dot(xn, p["wi"], tok["fc1"], policy)
-    h = glu_split(h, gated, act_fn)
-    y, st2 = mor_dot(h, p["wo"], tok["fc2"], policy)
+    with jax.named_scope("fc1"):
+        h, st1 = mor_dot(xn, p["wi"], tok["fc1"], policy)
+    with jax.named_scope("act"):
+        h = glu_split(h, gated, act_fn)
+    with jax.named_scope("fc2"):
+        y, st2 = mor_dot(h, p["wo"], tok["fc2"], policy)
     return y, {"fc1": st1, "fc2": st2}
 
 
@@ -262,10 +295,10 @@ def dense_block(p, x, tok, policy, cfg, mode, cache, cur_index, **attn_kw):
     a, new_cache, st_a = attn_sublayer(
         p, xn, tok, policy, cfg, mode, cache, cur_index, **attn_kw
     )
-    x = x + a
+    x = residual(x, a)
     xn2 = norm(p["ln2"], x, cfg)
     m, st_m = mlp_sublayer(p["mlp"], xn2, tok, policy, cfg)
-    x = x + m
+    x = residual(x, m)
     return x, new_cache, {**st_a, **st_m}
 
 
@@ -274,8 +307,8 @@ def moe_block(p, x, tok, policy, cfg, mode, cache, cur_index, **attn_kw):
     a, new_cache, st_a = attn_sublayer(
         p, xn, tok, policy, cfg, mode, cache, cur_index, **attn_kw
     )
-    x = x + a
+    x = residual(x, a)
     xn2 = norm(p["ln2"], x, cfg)
     m, st_m = moe_sublayer(p["moe"], xn2, tok, policy, cfg)
-    x = x + m
+    x = residual(x, m)
     return x, new_cache, {**st_a, **st_m}

@@ -418,10 +418,10 @@ def _hymba_block(p, x, tok, policy, cfg, mode, cache, cur_index, **attn_kw):
         p["ssm"], xn, tok, policy, cfg, mode,
         cache["ssm"] if cache is not None else None,
     )
-    x = x + a + s
+    x = B.residual(x, a, s)
     xn2 = B.norm(p["ln2"], x, cfg)
     m, st_m = B.mlp_sublayer(p["mlp"], xn2, tok, policy, cfg)
-    x = x + m
+    x = B.residual(x, m)
     new_cache = (
         {**new_kv, "ssm": new_ssm} if new_kv is not None else None
     )
@@ -431,13 +431,13 @@ def _hymba_block(p, x, tok, policy, cfg, mode, cache, cur_index, **attn_kw):
 def _mlstm_block(p, x, tok, policy, cfg, mode, cache, cur_index, **attn_kw):
     xn = B.norm(p["ln1"], x, cfg)
     y, new_cache, st = R.mlstm_mix(p, xn, tok, policy, cfg, mode, cache)
-    return x + y, new_cache, st
+    return B.residual(x, y), new_cache, st
 
 
 def _slstm_block(p, x, tok, policy, cfg, mode, cache, cur_index, **attn_kw):
     xn = B.norm(p["ln1"], x, cfg)
     y, new_cache, st = R.slstm_mix(p, xn, tok, policy, cfg, mode, cache)
-    return x + y, new_cache, st
+    return B.residual(x, y), new_cache, st
 
 
 def _wdec_block(p, x, tok, policy, cfg, mode, cache, cur_index,
@@ -451,7 +451,7 @@ def _wdec_block(p, x, tok, policy, cfg, mode, cache, cur_index,
         p, xn, tok, policy, cfg, mode, kv_cache, cur_index,
         kind="causal", use_rope=False,
     )
-    x = x + a
+    x = B.residual(x, a)
     # Cross-attention against encoder output (cached at prefill).
     xq = B.norm(p["lnx"], x, cfg)
     Bsz, S, _ = xq.shape
@@ -471,10 +471,10 @@ def _wdec_block(p, x, tok, policy, cfg, mode, cache, cur_index,
     xo = flash_attention(q, xk, xv, kind="full")
     xo = xo.reshape(Bsz, S, hq * hd)
     xa, st_xo = mor_dot(xo, p["xwo"], tok["xproj"], policy)
-    x = x + xa
+    x = B.residual(x, xa)
     xn2 = B.norm(p["ln2"], x, cfg)
     m, st_m = B.mlp_sublayer(p["mlp"], xn2, tok, policy, cfg)
-    x = x + m
+    x = B.residual(x, m)
     new_cache = None
     if new_kv is not None:
         new_cache = {
@@ -522,7 +522,11 @@ def _run_stack(
         body = jax.checkpoint(body, prevent_cse=False)
 
     xs = (block_params, block_tokens, cache)
-    x, (new_caches, stats) = jax.lax.scan(body, x, xs)
+    # ``stack``: the scan's own work (each layer's slice of the stacked
+    # weights, its outputs and gradients written back), apart from the
+    # layers' scopes inside the body.
+    with jax.named_scope("stack"):
+        x, (new_caches, stats) = jax.lax.scan(body, x, xs)
     if mode == "train":
         new_caches = None
     return x, new_caches, stats
@@ -558,13 +562,13 @@ def forward(
     mode ``cur_index`` -- scalar or (B,) vector -- is the position of
     the last incoming token per batch row (docs/serving.md).
     """
-    Vp = padded_vocab(cfg)
     embed = params["embed"]
 
     ids = batch["token"] if mode == "decode" else batch["tokens"]
-    x = embed[ids]  # gather, (B, S, d)
-    if cfg.family in ("dense", "vlm") and cfg.tie_embed:
-        x = x * jnp.asarray(cfg.d_model**0.5, x.dtype)  # gemma-style
+    with jax.named_scope("embed"):
+        x = embed[ids]  # gather, (B, S, d)
+        if cfg.family in ("dense", "vlm") and cfg.tie_embed:
+            x = x * jnp.asarray(cfg.d_model**0.5, x.dtype)  # gemma-style
 
     attn_kw: Dict[str, Any] = {"kind": "causal"}
     enc_out = None
@@ -612,22 +616,30 @@ def forward(
     all_stats["blocks"] = stats
 
     x = B.norm(params["final_norm"], x, cfg)
-    head = params["embed"].T if cfg.tie_embed else params["lm_head"]
-    if hasattr(head, "as_mixed_operand"):
-        # Real-quantized serving head (serve.quantized.QTensor): feed
-        # the stored per-block payloads straight into the mixed GEMM.
-        mo = head.as_mixed_operand()  # (Vp, d) quantization view
-        bsz, seq = x.shape[0], x.shape[1]
-        logits = kops.mixed_dot(
-            x.reshape(-1, x.shape[-1]), mo,
-            out_dtype=jnp.float32, backend=policy.weight.backend,
-        ).reshape(bsz, seq, head.shape[1])
-    else:
-        logits = jnp.einsum(
-            "bsd,dv->bsv", x, head, preferred_element_type=jnp.float32
-        )
-    # Mask padded vocab columns (Megatron-style; no resharding slice).
-    col = jax.lax.broadcasted_iota(jnp.int32, (1, 1, Vp), 2)
-    logits = jnp.where(col < cfg.vocab, logits, -1e30)
-    logits = constrain(logits, "batch", None, "model")
+    with jax.named_scope("head"):
+        logits = _head(cfg, policy, params, x)
     return logits, new_cache, all_stats
+
+
+def _head(cfg: ArchConfig, policy: MoRDotPolicy, params, x):
+    """Logits over the padded vocabulary, padded columns masked; the
+    product itself is scoped ``head/gemm``."""
+    head = params["embed"].T if cfg.tie_embed else params["lm_head"]
+    with jax.named_scope("gemm"):
+        if hasattr(head, "as_mixed_operand"):
+            # Real-quantized serving head (serve.quantized.QTensor): feed
+            # the stored per-block payloads straight into the mixed GEMM.
+            mo = head.as_mixed_operand()  # (Vp, d) quantization view
+            bsz, seq = x.shape[0], x.shape[1]
+            logits = kops.mixed_dot(
+                x.reshape(-1, x.shape[-1]), mo,
+                out_dtype=jnp.float32, backend=policy.weight.backend,
+            ).reshape(bsz, seq, head.shape[1])
+        else:
+            logits = jnp.einsum(
+                "bsd,dv->bsv", x, head, preferred_element_type=jnp.float32
+            )
+    # Mask padded vocab columns (Megatron-style; no resharding slice).
+    col = jax.lax.broadcasted_iota(jnp.int32, (1, 1, padded_vocab(cfg)), 2)
+    logits = jnp.where(col < cfg.vocab, logits, -1e30)
+    return constrain(logits, "batch", None, "model")

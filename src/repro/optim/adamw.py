@@ -116,6 +116,7 @@ def global_norm(tree) -> jnp.ndarray:
     return jnp.sqrt(jnp.sum(jnp.stack(leaves)))
 
 
+@jax.named_scope("optim")
 def adamw_update(
     cfg: AdamWConfig,
     grads,

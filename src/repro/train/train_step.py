@@ -71,10 +71,13 @@ class TrainConfig:
     guard: GuardPolicy | None = None
 
 
+@jax.named_scope("mor_quant")
+@jax.named_scope("stats")
 def summarize_mor_stats(
     fwd_stats, bwd_stats, opt_stats=None
 ) -> Dict[str, jnp.ndarray]:
-    """Reduce the per-layer/per-event stats pytrees to scalar metrics.
+    """Reduce the per-layer/per-event stats pytrees to scalar metrics
+    (scoped ``mor_quant/stats`` in the compiled step).
 
     Disabled-policy events (recipe 'off', decision column == -1) are
     excluded: a passthrough event reports ``frac_bf16 = 1.0`` by
@@ -247,29 +250,31 @@ def make_train_step(
         grad_stats = None
         new_ef = opt_state.ef
         if tcfg.compress_grads != "none":
-            g_params, new_ef, grad_stats = _compress(
-                g_params, mode=tcfg.compress_grads,
-                ef_state=opt_state.ef, policy=grad_policy,
-            )
+            with jax.named_scope("optim"), jax.named_scope("compress"):
+                g_params, new_ef, grad_stats = _compress(
+                    g_params, mode=tcfg.compress_grads,
+                    ef_state=opt_state.ef, policy=grad_policy,
+                )
 
         new_params, new_opt, opt_metrics = adamw_update(
             tcfg.optimizer, g_params, opt_state, moments=tcfg.moments,
             guard=tcfg.guard,
         )
-        # Params keep their dtypes (f32 norm scales stay f32): a step
-        # whose outputs differ from its inputs retraces and compiles
-        # again on its second call, and cannot update them in place.
-        new_params = jax.tree.map(
-            lambda n, p: n.astype(p.dtype), new_params, params
-        )
-        if "guard_skip" in opt_metrics and new_ef is not None:
-            # Skip-step EF preservation: compress_grads already folded
-            # this step's residual into `corrected` and re-split it; if
-            # the update is dropped, keeping the new residual would
-            # make the *next* step absorb this step's quantization
-            # error twice. Select the old residuals back (bit-exact).
-            ok = opt_metrics["guard_skip"] < 0.5
-            new_ef = tree_select(ok, new_ef, opt_state.ef)
+        with jax.named_scope("optim"):
+            # Params keep their dtypes (f32 norm scales stay f32): a step
+            # whose outputs differ from its inputs retraces and compiles
+            # again on its second call, and cannot update them in place.
+            new_params = jax.tree.map(
+                lambda n, p: n.astype(p.dtype), new_params, params
+            )
+            if "guard_skip" in opt_metrics and new_ef is not None:
+                # Skip-step EF preservation: compress_grads already folded
+                # this step's residual into `corrected` and re-split it; if
+                # the update is dropped, keeping the new residual would
+                # make the *next* step absorb this step's quantization
+                # error twice. Select the old residuals back (bit-exact).
+                ok = opt_metrics["guard_skip"] < 0.5
+                new_ef = tree_select(ok, new_ef, opt_state.ef)
         new_opt = new_opt._replace(ef=new_ef)
         # Optimizer-event rows (stats v4): gradient-compression events
         # plus the packed-moment encode events adamw_update reports.
@@ -289,7 +294,8 @@ def make_train_step(
             ),
         }
         if new_ef is not None:
-            metrics["ef_norm"] = global_norm(new_ef)
+            with jax.named_scope("optim"):
+                metrics["ef_norm"] = global_norm(new_ef)
         return new_params, new_opt, metrics
 
     return train_step

@@ -9,10 +9,18 @@ slots and a 32,000-column logits gradient. A refused kernel (SMEM or
 VMEM size, lane alignment, an unsupported cast) fails here at no chip
 time.
 
+The kernels keep their instruction names under the program's named
+scopes, and the tiny train step compiled for the chip names one layer
+per instruction (``tests/chipbench/scoped_hlo.py``).
+
 The topology is described inside a module fixture, never at import: the
 TPU library admits one process at a time, and every test worker imports
 every test file.
 """
+import pathlib
+import re
+import sys
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -20,6 +28,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.core.formats import NVFP4_MICRO
 from repro.kernels import ops as kops
+from repro.kernels.flash_attention import flash_attention_fwd
 from repro.kernels.gam_quant import gam_quant_blocks
 from repro.kernels.mor_select import mor_select_blocks
 from repro.kernels.ref import (
@@ -143,3 +152,68 @@ def test_mixed_gemm_compiles(spec, rows, n, k, recipe):
     b = _operand(spec, n, k, BLOCK[0], fp8=True, bf16=True,
                  nvfp4=recipe == "sub4")
     _compile(lambda a, b: kops.mixed_gemm(a, b, backend="pallas"), a, b)
+
+
+def _kernel_cases(spec):
+    """Each Pallas kernel at a small shape: (name, fn, argument shapes)."""
+    x = spec((256, 256), jnp.bfloat16)
+    mixed_a = _operand(spec, 256, 256, kops.decode_row_block(256),
+                       fp8=False, bf16=True)
+    mixed_b = _operand(spec, 256, 256, BLOCK[0], fp8=True, bf16=True)
+    qkv = spec((2, 512, 128), jnp.bfloat16)
+    return {
+        "gam_quant_blocks": (
+            lambda x, m: gam_quant_blocks(x, m, block=BLOCK),
+            (x, spec((), jnp.float32))),
+        "mor_select_blocks": (
+            lambda x, m, g: mor_select_blocks(x, m, g, block=BLOCK,
+                                              mode="sub3", emit="select"),
+            (x, spec((3,), jnp.float32), spec((), jnp.float32))),
+        "mixed_gemm_blocks": (
+            lambda a, b: kops.mixed_gemm(a, b, backend="pallas"),
+            (mixed_a, mixed_b)),
+        "flash_attention_fwd": (flash_attention_fwd, (qkv, qkv, qkv)),
+    }
+
+
+# fp8_gemm is left out: its (1, 1) blocks of scales do not lower for the
+# TPU at all.
+@pytest.mark.parametrize("kernel", [
+    "gam_quant_blocks", "mor_select_blocks", "mixed_gemm_blocks",
+    "flash_attention_fwd"])
+def test_kernel_keeps_its_name_under_scopes(spec, kernel):
+    """A kernel called under the program's layer scopes keeps the
+    instruction name the trace readers match, and its op_name carries
+    the scopes."""
+    fn, args = _kernel_cases(spec)[kernel]
+
+    def scoped(*a):
+        with jax.named_scope("attn"), jax.named_scope("mor_quant"):
+            return fn(*a)
+
+    text = _compile(scoped, *args).as_text()
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert calls
+    for line in calls:
+        assert re.match(rf"\s*(ROOT )?%{kernel}(\.\d+)? = ", line), line
+        assert "/attn/mor_quant/" in line, line
+
+
+def test_train_step_names_one_layer_per_instruction(one_chip,
+                                                    no_persistent_cache):
+    """The tiny train cell's step compiled for one v5e chip with the
+    kernels on Pallas: the instructions the trace readers attribute."""
+    here = pathlib.Path(__file__).resolve().parent
+    for path in (here.parent, here / "chipbench"):
+        if str(path) not in sys.path:
+            sys.path.insert(0, str(path))
+    from chipbench.metrics._scopes import layer_of
+    from chipbench_tiny import tiny_cell
+    from scoped_hlo import check_step_scopes, instructions, train_step_hlo
+
+    text = train_step_hlo(tiny_cell("train"), one_chip, backend="pallas")
+    check_step_scopes(text)
+    quant = [op for name, _, op in instructions(text)
+             if name.startswith("gam_quant_blocks")]
+    assert quant and all(layer_of(op) == "mor_quant" for op in quant)

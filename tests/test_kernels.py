@@ -10,7 +10,11 @@ from repro.core.partition import Partition
 from repro.kernels import ref as kref
 from repro.kernels.flash_attention import flash_attention_fwd
 from repro.kernels.fp8_gemm import fp8_gemm
-from repro.kernels.gam_quant import gam_quant_blocks
+from repro.kernels.gam_quant import (
+    VMEM_TILE_BUDGET,
+    gam_quant_blocks,
+    tile_for,
+)
 from repro.kernels.ops import gam_quant
 
 
@@ -20,7 +24,10 @@ def _rand(shape, seed=0, scale=1.0, dtype=jnp.float32):
 
 
 # ------------------------------------------------------------- gam_quant --
-@pytest.mark.parametrize("shape", [(128, 128), (256, 384), (512, 128)])
+# (1024, 2048) and (2048, 1024) run several grid steps of many blocks;
+# (384, 1280) takes a tile that only some block counts divide.
+@pytest.mark.parametrize("shape", [(128, 128), (256, 384), (512, 128),
+                                   (1024, 2048), (2048, 1024), (384, 1280)])
 @pytest.mark.parametrize("block", [(128, 128), (64, 64)])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 @pytest.mark.parametrize("algo", ["gam", "e8m0", "fp32_amax"])
@@ -53,6 +60,37 @@ def test_gam_quant_kernel_matches_ref(shape, block, dtype, algo):
         np.asarray(err), np.asarray(rerr), rtol=2e-5, atol=1e-5
     )
     np.testing.assert_array_equal(np.asarray(cnt), np.asarray(rcnt))
+
+
+# Every operand shape the nemotron3-8b train cell quantizes (8192 tokens,
+# d_model 4096, qkv 12288, d_ff 16384, and their transposes).
+@pytest.mark.parametrize("shape", [
+    (8192, 4096), (8192, 12288), (8192, 16384), (12288, 4096),
+    (16384, 4096), (4096, 4096), (4096, 12288), (4096, 16384),
+])
+def test_gam_quant_tile_rule_engages(shape):
+    """The train cell's operands each get a tile of many scale blocks
+    whose double-buffered input and output tiles fit the VMEM budget."""
+    tm, tk = tile_for(shape, (128, 128), jnp.bfloat16)
+    assert shape[0] % tm == 0 and shape[1] % tk == 0
+    assert tm % 128 == 0 and tk % 128 == 0
+    assert (tm // 128) * (tk // 128) > 1
+    assert 2 * 2 * 2 * tm * tk <= VMEM_TILE_BUDGET
+
+
+@pytest.mark.parametrize("shape, block, dtype, tile", [
+    ((128, 128), (128, 128), jnp.bfloat16, (128, 128)),
+    # 17 block columns: no whole number of them up to the limit divides
+    # but one, and a single block row.
+    ((128, 17 * 128), (128, 128), jnp.float32, (128, 128)),
+    # Only 5 or 10 block columns divide 20 under the limit of 16.
+    ((384, 1280), (64, 64), jnp.float32, (384, 640)),
+    # 64-wide blocks only pair up into 128-lane tiles: 34 columns of
+    # them give pairs, as 17 is over the limit.
+    ((128, 64 * 34), (64, 64), jnp.float32, (128, 128)),
+])
+def test_gam_quant_tile_rule_falls_back(shape, block, dtype, tile):
+    assert tile_for(shape, block, dtype) == tile
 
 
 def test_gam_quant_no_saturation_property():

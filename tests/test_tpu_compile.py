@@ -88,7 +88,8 @@ def _compile(fn, *args):
 
 
 @pytest.mark.parametrize(
-    "shape", [(TOKENS, D_MODEL), (D_FF, D_MODEL)], ids=["act", "weight"]
+    "shape", [(TOKENS, D_MODEL), (D_FF, D_MODEL), (TOKENS, D_FF)],
+    ids=["act", "weight", "act_ff"],
 )
 def test_gam_quant_compiles(spec, shape):
     _compile(

@@ -1,8 +1,11 @@
 """Operations and bytes of the work a cell asks for, from shapes alone.
 
-These are the yardstick's own counts: they follow the mathematics of a
-dense decoder step, not what any kernel happens to do, so a faster
-kernel cannot change them. The chip's peaks come from ``peaks.json``.
+These are the yardstick's own counts: they follow the mathematics of
+the work, not what any kernel happens to do, so a faster kernel cannot
+change them. A step's operations follow its model family
+(``train_step_flops`` and ``decode_step_flops`` in
+``families/<family>.py``); here are the bytes of single kernels, the
+bytes of HLO shapes, and the chip's peaks from ``peaks.json``.
 """
 from __future__ import annotations
 
@@ -25,42 +28,6 @@ def peaks(device_kind: str) -> Dict[str, float]:
         raise KeyError(f"no published peaks for device kind "
                        f"{device_kind!r}; known: {sorted(table)}")
     return table[device_kind]
-
-
-def matmul_params_per_layer(cfg) -> int:
-    """Weights of one dense block's four matrix products."""
-    d, f = cfg.d_model, cfg.d_ff
-    hq, hkv, hd = cfg.n_heads, cfg.n_kv, cfg.head_dim
-    return d * (hq + 2 * hkv) * hd + hq * hd * d + 2 * d * f
-
-
-def matmul_params(cfg) -> int:
-    """All matrix-product weights: the blocks and the output head. The
-    embedding is a lookup, not a product, and is not counted."""
-    return cfg.n_layers * matmul_params_per_layer(cfg) + cfg.d_model * cfg.vocab
-
-
-def causal_attention_flops(cfg, seq: int) -> float:
-    """Forward operations of causal attention over one sequence, all
-    layers: scores and the weighted sum over the lower triangle."""
-    return cfg.n_layers * 2.0 * seq * seq * cfg.n_heads * cfg.head_dim
-
-
-def train_step_flops(cfg, batch: int, seq: int) -> float:
-    """Forward and backward operations of one step (3x forward);
-    recomputation for remat is not counted."""
-    fwd = 2.0 * matmul_params(cfg) * batch * seq \
-        + batch * causal_attention_flops(cfg, seq)
-    return 3.0 * fwd
-
-
-def decode_step_flops(cfg, cache_lens: Sequence[int]) -> float:
-    """Operations of one decode step over the slots that decode: each
-    reads its own cache of ``len`` positions (the new one included)."""
-    n = len(cache_lens)
-    attn = sum(4.0 * cfg.n_layers * cfg.n_heads * cfg.head_dim * l
-               for l in cache_lens)
-    return 2.0 * matmul_params(cfg) * n + attn
 
 
 # ---------------------------------------------------- shapes in HLO --

@@ -42,9 +42,8 @@ def read_all(cell, trace, counters, device_kind: str, chips: int
              ) -> Dict[str, Dict[str, Any]]:
     """Every per-layer metric of the cell that its reader finds."""
     from .. import counts
-    from ..spec import arch_config
 
-    ctx = Ctx(cell, arch_config(cell.config), trace, counters,
+    ctx = Ctx(cell, cell.family.arch_config(cell.config), trace, counters,
               counts.peaks(device_kind), chips)
     out = {}
     for m in cell.per_layer:

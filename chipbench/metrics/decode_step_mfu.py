@@ -1,9 +1,8 @@
 """Share of the chip's bf16 peak that a decode step's useful work
 reaches: the matrix products of the slots that decode and attention over
-the cache lengths they read (``counts.decode_step_flops``, the mean over
-the decode steps the traced window launched), over the mean device time
-of the decode program's runs."""
-from chipbench import counts
+the cache lengths they read (the family's ``decode_step_flops``, the
+mean over the decode steps the traced window launched), over the mean
+device time of the decode program's runs."""
 from chipbench.metrics._decode import decode_runs
 
 
@@ -13,6 +12,7 @@ def read(ctx):
             if c[0] == "decode"]
     if not runs or not lens:
         return None
-    flops = sum(counts.decode_step_flops(ctx.cfg, l) for l in lens) / len(lens)
+    count = ctx.cell.family.decode_step_flops
+    flops = sum(count(ctx.cfg, l) for l in lens) / len(lens)
     spent = sum(r.dur for r in runs) / len(runs) / 1e9
     return 100.0 * flops / spent / ctx.peaks["peak_flops_bf16"]

@@ -1,6 +1,6 @@
 """Whole training step's share of the chips' bf16 peak: the forward and
 backward operations of every matrix product and of causal attention
-(``counts.train_step_flops``; the embedding lookup and remat
+(the family's ``train_step_flops``; the embedding lookup and remat
 recomputation not counted) times the steps of the traced run's window,
 over its wall time, chips and peak."""
 

@@ -54,8 +54,10 @@ def serve_seed(cell, seed: int, seconds: float) -> dict:
                                 chk["max_requests"])
     seqs, picks, served = serve.check_inputs(sample)
     pad = cell.traffic["engine"]["max_seq"]
-    ref = reference.serve_logits(cfg, seed, seqs, picks, pad_to=pad)
-    ctl = reference.serve_logits(cfg, seed, seqs, picks, bits=4, pad_to=pad)
+    fam = cell.family
+    ref = reference.serve_logits(fam, cfg, seed, seqs, picks, pad_to=pad)
+    ctl = reference.serve_logits(fam, cfg, seed, seqs, picks, bits=4,
+                                 pad_to=pad)
     return {
         "program": check.serve_numbers(served, ref),
         "control_int4": check.serve_numbers(

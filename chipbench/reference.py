@@ -1,15 +1,16 @@
-"""The plain reference: a dense decoder in float32 ``jax.numpy``.
+"""The plain reference in float32 ``jax.numpy``: the shared parts.
 
-It imports nothing of the program. Its equations follow the published
-description of the block as the repository's configurations state it:
-RMS norm scaled by ``1 + scale`` (eps 1e-6), rotary embedding over the
-whole head (halves rotated, theta from the configuration), causal
-grouped-query attention in float32 with scale ``head_dim ** -0.5``,
-squared-ReLU MLP, a final norm and an untied output head; the training
-loss is the mean token cross-entropy and the optimizer AdamW with
-global-norm clipping and a linear-warmup cosine schedule. Every matrix
-product runs at ``highest`` precision, so on the TPU it is float32 and
-not bfloat16 passes.
+It imports nothing of the program. A model family's equations (one
+unit of its stack, and the batch's loss and gradient) are in
+``families/<family>.py``, which builds them from the helpers here:
+``mm`` (a matrix product at ``highest`` precision, so on the TPU it is
+float32 and not bfloat16 passes), ``rms`` (RMS norm scaled by ``1 +
+scale``, eps 1e-6), ``rope`` (rotary embedding, halves rotated) and
+``row_mean_grads`` (the gradient of a mean over rows, one row at a
+time). Here are what every family shares: the optimizer, AdamW with
+global-norm clipping and a linear-warmup cosine schedule; the norms a
+training cell compares; and serving logits, one unit of the stack at a
+time.
 
 ``quant_bits=4`` is the control: the same mathematics with every matrix
 product's operands (training: activations and weights, straight-through
@@ -60,7 +61,7 @@ def mm(x, w, bits: int = 0, quant_act: bool = True):
     return jnp.matmul(x, w, precision=HIGHEST)
 
 
-# ------------------------------------------------------------- model --
+# ------------------------------------------------------------ layers --
 def rms(x, scale, eps=1e-6):
     var = jnp.mean(x * x, axis=-1, keepdims=True)
     return x * jax.lax.rsqrt(var + eps) * (1.0 + scale)
@@ -76,63 +77,20 @@ def rope(x, pos, theta):
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
 
 
-def layer(cfg, p, x, bits=0, quant_act=True):
-    """One dense block over one sequence x (S, d); p holds float32
-    matrices wqkv, wo, wi, wo2 and norm scales ln1, ln2."""
-    S = x.shape[0]
-    hq, hkv, hd = cfg.n_heads, cfg.n_kv, cfg.head_dim
-    pos = jnp.arange(S)
-    xn = rms(x, p["ln1"])
-    qkv = mm(xn, p["wqkv"], bits, quant_act)
-    q, k, v = jnp.split(qkv, [hq * hd, (hq + hkv) * hd], axis=-1)
-    q = rope(q.reshape(S, hq, hd), pos, cfg.rope_theta)
-    k = rope(k.reshape(S, hkv, hd), pos, cfg.rope_theta)
-    v = v.reshape(S, hkv, hd)
-    g = hq // hkv
-    k = jnp.repeat(k, g, axis=1)
-    v = jnp.repeat(v, g, axis=1)
-    s = jnp.einsum("qhd,khd->hqk", q, k, precision=HIGHEST) * hd ** -0.5
-    causal = pos[None, :] <= pos[:, None]
-    s = jnp.where(causal[None], s, -jnp.inf)
-    a = jax.nn.softmax(s, axis=-1)
-    o = jnp.einsum("hqk,khd->qhd", a, v, precision=HIGHEST)
-    x = x + mm(o.reshape(S, hq * hd), p["wo"], bits, quant_act)
-    xn = rms(x, p["ln2"])
-    h = jnp.square(jax.nn.relu(mm(xn, p["wi"], bits, quant_act)))
-    return x + mm(h, p["wo2"], bits, quant_act)
-
-
-def _stack(params):
-    b = params["blocks"]["dense"]
-    return {"wqkv": b["wqkv"], "wo": b["wo"], "wi": b["mlp"]["wi"],
-            "wo2": b["mlp"]["wo"], "ln1": b["ln1"]["scale"],
-            "ln2": b["ln2"]["scale"]}
-
-
-def row_loss(cfg, params, tokens, labels, bits=0):
-    """Mean cross-entropy of one sequence; params float32, program tree."""
-    x = params["embed"][tokens]
-
-    @jax.checkpoint
-    def body(x, p):
-        return layer(cfg, p, x, bits), None
-
-    x, _ = jax.lax.scan(body, x, _stack(params))
-    x = rms(x, params["final_norm"]["scale"])
-    logits = mm(x, params["lm_head"], bits)[:, : cfg.vocab]
-    lse = jax.nn.logsumexp(logits, axis=-1)
-    gold = jnp.take_along_axis(logits, labels[:, None], axis=-1)[:, 0]
-    return jnp.mean(lse - gold)
-
-
-def batch_grads(cfg, params, batch, bits=0, rows=None):
-    """(loss, grads) of the batch mean, accumulated one row at a time.
-    ``rows`` keeps only the first ``rows`` rows (a planted fault)."""
+def batch_grads(fam, cfg, params, batch, bits=0, rows=None):
+    """(loss, grads) of the family's loss over the batch. ``rows`` keeps
+    only the first ``rows`` rows (a planted fault)."""
     toks, labs = batch["tokens"], batch["labels"]
     if rows is not None:
         toks, labs = toks[:rows], labs[:rows]
+    return fam.batch_grads(cfg, params, toks, labs, bits)
+
+
+def row_mean_grads(row_loss, params, toks, labs):
+    """(loss, grads) of the mean of ``row_loss(params, tokens, labels)``
+    over the rows, accumulated one row at a time."""
     n = toks.shape[0]
-    vg = jax.value_and_grad(functools.partial(row_loss, cfg, bits=bits))
+    vg = jax.value_and_grad(row_loss)
 
     def acc(carry, row):
         loss, grads = carry
@@ -185,7 +143,7 @@ def leaf_norms(tree) -> Dict[str, np.ndarray]:
     return out
 
 
-def change_norms(cfg, seed: int, master) -> Dict[str, np.ndarray]:
+def change_norms(fam, cfg, seed: int, master) -> Dict[str, np.ndarray]:
     """Norms of ``master`` minus the seeded initial weights, each leaf
     made again from the seed (one per layer for a stacked leaf)."""
     key = W.base_key(seed)
@@ -193,7 +151,7 @@ def change_norms(cfg, seed: int, master) -> Dict[str, np.ndarray]:
     for path, x in W.flatten(master).items():
         @jax.jit
         def norm(x, key, path=path):
-            d = x - W.make_leaf(key, cfg, path).astype(jnp.float32)
+            d = x - W.make_leaf(fam, key, cfg, path).astype(jnp.float32)
             axes = tuple(range(1 if path.startswith("blocks/") else 0,
                                d.ndim))
             return jnp.sqrt(jnp.sum(jnp.square(d), axis=axes))
@@ -202,8 +160,9 @@ def change_norms(cfg, seed: int, master) -> Dict[str, np.ndarray]:
     return out
 
 
-def train_readings(cfg, job, seed: int, batches: Sequence, bits: int = 0,
-                   rows: Optional[int] = None, steps: int = 3):
+def train_readings(fam, cfg, job, seed: int, batches: Sequence,
+                   bits: int = 0, rows: Optional[int] = None,
+                   steps: int = 3):
     """The reference's side of a training cell's check: the loss of each
     of the first ``steps`` steps, the norms of the first step's gradient
     as the optimizer takes it (clipped), and the norms of each
@@ -215,9 +174,9 @@ def train_readings(cfg, job, seed: int, batches: Sequence, bits: int = 0,
     with jax.default_matmul_precision("highest"):
         master = jax.jit(lambda p: jax.tree.map(
             lambda x: x.astype(jnp.float32), p), donate_argnums=0)(
-            W.make_params(cfg, seed))
-        grads_fn = jax.jit(functools.partial(batch_grads, cfg, bits=bits,
-                                             rows=rows))
+            W.make_params(fam, cfg, seed))
+        grads_fn = jax.jit(functools.partial(batch_grads, fam, cfg,
+                                             bits=bits, rows=rows))
         losses, g1 = [], None
         m_host: Dict[str, np.ndarray] = {}
         v_host: Dict[str, np.ndarray] = {}
@@ -248,28 +207,26 @@ def train_readings(cfg, job, seed: int, batches: Sequence, bits: int = 0,
                                                   np.asarray(vv))
                 del g, mm_, vv
             master = W.nest(flat_m)
-        change = change_norms(cfg, seed, master)
+        change = change_norms(fam, cfg, seed, master)
     return {"losses": losses, "grad_norms": g1, "change_norms": change}
 
 
 # ----------------------------------------------------------- serving --
-def _layer_params(cfg, key, l, bits):
-    p = {
-        "wqkv": W.make_leaf(key, cfg, "blocks/dense/wqkv", l),
-        "wo": W.make_leaf(key, cfg, "blocks/dense/wo", l),
-        "wi": W.make_leaf(key, cfg, "blocks/dense/mlp/wi", l),
-        "wo2": W.make_leaf(key, cfg, "blocks/dense/mlp/wo", l),
-        "ln1": W.make_leaf(key, cfg, "blocks/dense/ln1/scale", l),
-        "ln2": W.make_leaf(key, cfg, "blocks/dense/ln2/scale", l),
-    }
-    p = jax.tree.map(lambda x: x.astype(jnp.float32), p)
+def _layer_params(fam, cfg, key, l, bits):
+    """Unit ``l``'s weights for the family's ``layer``, in float32, made
+    from the seed; under ``bits=4`` each matrix rounded to int4 along its
+    contraction axis."""
+    flat = {path: W.make_leaf(fam, key, cfg, path, l)
+            for path in fam.leaf_table(cfg) if path.startswith("blocks/")}
+    p = jax.tree.map(lambda x: x.astype(jnp.float32),
+                     fam.stack(W.nest(flat)))
     if bits == 4:
-        p = {k: int4_blocks(v, 0) if v.ndim == 2 else v
-             for k, v in p.items()}
+        p = jax.tree.map(
+            lambda v: int4_blocks(v, v.ndim - 2) if v.ndim >= 2 else v, p)
     return p
 
 
-def serve_logits(cfg, seed: int, seqs: List[np.ndarray],
+def serve_logits(fam, cfg, seed: int, seqs: List[np.ndarray],
                  picks: List[np.ndarray], bits: int = 0,
                  pad_to: int = 2048, head_chunk: int = 8192):
     """Logits (float32) of the reference at chosen positions.
@@ -277,7 +234,8 @@ def serve_logits(cfg, seed: int, seqs: List[np.ndarray],
     ``seqs[i]`` is a token sequence and ``picks[i]`` the positions whose
     next-token logits are wanted. The model runs layer by layer over all
     sequences (padded to ``pad_to``; causal attention keeps the padding
-    out), each layer's weights made from the seed as it is needed.
+    out): the family's ``layer`` once for each unit that the ``blocks/``
+    leaves stack, each unit's weights made from the seed as it is needed.
     Returns one (len(picks[i]), vocab) array per sequence."""
     key = W.base_key(seed)
     n = len(seqs)
@@ -285,23 +243,23 @@ def serve_logits(cfg, seed: int, seqs: List[np.ndarray],
     for i, s in enumerate(seqs):
         toks[i, : len(s)] = s
     with jax.default_matmul_precision("highest"):
-        embed = jax.jit(lambda k: W.make_leaf(k, cfg, "embed"))(key)
+        embed = jax.jit(lambda k: W.make_leaf(fam, k, cfg, "embed"))(key)
         x = jax.jit(lambda e, t: e[t].astype(jnp.float32))(embed,
                                                            jnp.asarray(toks))
         del embed
-        gen = jax.jit(functools.partial(_layer_params, cfg, bits=bits))
+        gen = jax.jit(functools.partial(_layer_params, fam, cfg, bits=bits))
         run = jax.jit(lambda p, x: jax.lax.map(
-            lambda xi: layer(cfg, p, xi, bits, quant_act=False), x))
-        for l in range(cfg.n_layers):
+            lambda xi: fam.layer(cfg, p, xi, bits, quant_act=False), x))
+        for l in range(W.stack_depth(fam, cfg)):
             x = run(gen(key, l), x)
-        fin = W.make_leaf(key, cfg, "final_norm/scale")
+        fin = W.make_leaf(fam, key, cfg, "final_norm/scale")
         rows = [np.asarray(p, np.int32) for p in picks]
         flat_i = np.concatenate([np.full(len(r), i) for i, r in
                                  enumerate(rows)])
         flat_p = np.concatenate(rows)
         h = rms(x[flat_i, flat_p], fin.astype(jnp.float32))
         del x
-        head = jax.jit(lambda k: W.make_leaf(k, cfg, "lm_head"))(key)
+        head = jax.jit(lambda k: W.make_leaf(fam, k, cfg, "lm_head"))(key)
 
         @jax.jit
         def logits_of(h, head):

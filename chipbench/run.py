@@ -148,7 +148,7 @@ def run_serve(cell, args, devs, counters, programs):
     if not sample:
         return len(plans), failed, {"logit_gap": math.inf}, tracer
     seqs, picks, served = serve.check_inputs(sample)
-    ref = reference.serve_logits(cfg, args.seed, seqs, picks,
+    ref = reference.serve_logits(cell.family, cfg, args.seed, seqs, picks,
                                  pad_to=cell.traffic["engine"]["max_seq"])
     return len(plans), failed, check.serve_numbers(served, ref), tracer
 

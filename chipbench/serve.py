@@ -19,7 +19,6 @@ from typing import Any, Dict, List, Optional
 import numpy as np
 
 from . import weights as W
-from .spec import arch_config
 
 
 @dataclasses.dataclass
@@ -68,9 +67,9 @@ def make_engine(cell, seed: int):
     from repro.core import MoRPolicy, paper_default
     from repro.serve import Engine, ServeConfig
 
-    cfg = arch_config(cell.config)
+    cfg = cell.family.arch_config(cell.config)
     eng_spec = cell.traffic["engine"]
-    params = W.make_params(cfg, seed)
+    params = W.make_params(cell.family, cfg, seed)
     W.check_tree(cfg, params)
     quant = eng_spec.get("quantize")
     eng = Engine(

@@ -2,33 +2,20 @@
 
 Everything that belongs to one configuration, one traffic mix or one
 cell is a JSON file of its own under this directory; ``BENCHMARK.json``
-at the repository root names them.
+at the repository root names them. A configuration's ``family`` names
+the Python file ``families/<family>.py`` that maps its keys onto the
+program and holds its reference equations and operation counts.
 """
 from __future__ import annotations
 
 import dataclasses
+import importlib.util
 import json
 import pathlib
 from typing import Any, Dict, List, Optional
 
 HERE = pathlib.Path(__file__).resolve().parent
 ROOT = HERE.parent
-
-# Source keys of a dense decoder's config.json -> the repository's
-# ArchConfig fields. A configuration file states its model in the
-# source's own keys; this table is the only place that maps them.
-_DENSE_KEYS = {
-    "num_hidden_layers": "n_layers",
-    "hidden_size": "d_model",
-    "num_attention_heads": "n_heads",
-    "num_key_value_heads": "n_kv",
-    "head_dim": "head_dim",
-    "intermediate_size": "d_ff",
-    "vocab_size": "vocab",
-    "hidden_act": "act",
-    "rope_theta": "rope_theta",
-}
-
 
 class SpecError(ValueError):
     """A cell, configuration or traffic file is missing or malformed."""
@@ -56,6 +43,11 @@ class Cell:
     limits: Optional[Dict[str, Any]]
     end_to_end: List[Dict[str, Any]]
     per_layer: List[Dict[str, Any]]
+    # The module of the configuration's family file, loaded with the cell.
+    family: Any = dataclasses.field(init=False, repr=False, compare=False)
+
+    def __post_init__(self):
+        object.__setattr__(self, "family", family_of(self.config))
 
     @property
     def kind(self) -> str:
@@ -78,7 +70,7 @@ def load_cell(name: str, root: pathlib.Path = ROOT) -> Cell:
     traffic = _load(HERE / "traffic" / f"{w['traffic']}.json")
     lim_path = HERE / "limits" / f"{name}.json"
     limits = _load(lim_path) if lim_path.exists() else None
-    for key in ("source", "reduced", "assumed"):
+    for key in ("source", "reduced", "assumed", "family"):
         if key not in conf:
             raise SpecError(f"configuration {w['config']} lacks {key!r}")
     if traffic.get("kind") not in ("train", "serve"):
@@ -91,14 +83,16 @@ def load_cell(name: str, root: pathlib.Path = ROOT) -> Cell:
     )
 
 
-def arch_config(conf: Dict[str, Any]):
-    """The repository's ArchConfig for a configuration file."""
-    from repro.configs.base import ArchConfig
-
-    if conf.get("family") != "dense":
-        raise SpecError(f"family {conf.get('family')!r} has no mapping")
-    kw = {field: conf[key] for key, field in _DENSE_KEYS.items()}
-    return ArchConfig(
-        name=conf["name"], family="dense", unit=("dense",),
-        norm="rms", dtype="bfloat16", **kw,
-    )
+def family_of(conf: Dict[str, Any]):
+    """The module of ``families/<family>.py``, the file that the
+    configuration's ``family`` names, loaded by its path."""
+    name = conf.get("family")
+    path = HERE / "families" / f"{name}.py"
+    if not isinstance(name, str) or "/" in name or not path.is_file():
+        raise SpecError(f"family {name!r} has no file: add "
+                        f"chipbench/families/{name}.py")
+    spec = importlib.util.spec_from_file_location(
+        f"chipbench.families.{name.replace('.', '_')}", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod

@@ -16,8 +16,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from . import counts, reference, weights as W
-from .spec import arch_config
+from . import reference, weights as W
 
 CHECKED_STEPS = 3
 
@@ -55,7 +54,7 @@ class TrainSession:
 
         self.cell, self.seed = cell, seed
         self.job = job = cell.traffic
-        self.cfg = cfg = arch_config(cell.config)
+        self.cfg = cfg = cell.family.arch_config(cell.config)
         self.trainer = Trainer(
             cfg, policy_of(job),
             TrainConfig(optimizer=AdamWConfig(**job["optimizer"])),
@@ -63,7 +62,7 @@ class TrainSession:
             DataConfig(vocab=cfg.vocab, seq_len=job["seq_len"],
                        global_batch=job["global_batch"]),
         )
-        self.params = W.make_params(cfg, seed)
+        self.params = W.make_params(cell.family, cfg, seed)
         W.check_tree(cfg, self.params)
         self.opt = jax.jit(init_opt_state)(self.params)
         self.batch_at = make_batch_fn(cfg, job)
@@ -92,8 +91,8 @@ class TrainSession:
                       reference.leaf_norms(self.opt.m).items()}
         for _ in range(CHECKED_STEPS - 1):
             self.step()
-        change = reference.change_norms(self.cfg, self.seed,
-                                        self.opt.master)
+        change = reference.change_norms(self.cell.family, self.cfg,
+                                        self.seed, self.opt.master)
         losses = [float(l) for l in self.losses[:CHECKED_STEPS]]
         return {"losses": losses, "grad_norms": grad_norms,
                 "change_norms": change}
@@ -108,12 +107,13 @@ class TrainSession:
 def reference_readings(cell, seed: int, bits: int = 0,
                        rows: Optional[int] = None):
     """The reference's side, on the same batches as the checked steps."""
-    cfg, job = arch_config(cell.config), cell.traffic
+    cfg, job = cell.family.arch_config(cell.config), cell.traffic
     batch_at = make_batch_fn(cfg, job)
     key = data_key(seed)
     batches = [jax.device_get(batch_at(key, i)) for i in range(CHECKED_STEPS)]
-    return reference.train_readings(cfg, job, seed, batches, bits=bits,
-                                    rows=rows, steps=CHECKED_STEPS)
+    return reference.train_readings(cell.family, cfg, job, seed, batches,
+                                    bits=bits, rows=rows,
+                                    steps=CHECKED_STEPS)
 
 
 def window(sess: TrainSession, seconds: float, tracer=None,
@@ -151,5 +151,6 @@ def window(sess: TrainSession, seconds: float, tracer=None,
 
 
 def step_flops(cell) -> float:
-    cfg, job = arch_config(cell.config), cell.traffic
-    return counts.train_step_flops(cfg, job["global_batch"], job["seq_len"])
+    cfg, job = cell.family.arch_config(cell.config), cell.traffic
+    return cell.family.train_step_flops(cfg, job["global_batch"],
+                                        job["seq_len"])

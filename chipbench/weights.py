@@ -4,15 +4,17 @@ Both the system under test and the plain reference take their weights
 from here, so the reference never reads anything the program made. Every
 matrix is drawn as ``normal * std`` in float32 and stored in bfloat16,
 the type it is served and trained in; norm scales are float32 zeros
-(the repository's RMS norm multiplies by ``1 + scale``). Each leaf, and
-each layer of a layer-stacked leaf, has a key of its own, so the
-reference can make one layer at a time.
+(the repository's RMS norm multiplies by ``1 + scale``). The family
+file's ``leaf_table(cfg)`` lists the leaves: ``path -> (shape, dtype,
+std)``, a leaf under ``blocks/`` stacked over the units of the stack on
+its first axis. Each leaf, and each unit of a stacked leaf, has a key of
+its own (from its path), so the reference can make one unit at a time.
 """
 from __future__ import annotations
 
 import functools
 import zlib
-from typing import Any, Dict, Tuple
+from typing import Any, Dict
 
 import jax
 import jax.numpy as jnp
@@ -35,28 +37,6 @@ def padded_vocab(cfg) -> int:
     return -(-cfg.vocab // 128) * 128
 
 
-def leaf_table(cfg) -> Dict[str, Tuple[Tuple[int, ...], Any, float]]:
-    """``path -> (shape, dtype, std)`` of a dense decoder's params; std 0
-    marks a zero-initialised norm scale. Paths follow the tree of
-    ``repro.models.init_params``."""
-    d, L, f = cfg.d_model, cfg.n_layers, cfg.d_ff
-    hq, hkv, hd = cfg.n_heads, cfg.n_kv, cfg.head_dim
-    vp = padded_vocab(cfg)
-    out_std = STD / max(1.0, (2 * L) ** 0.5)
-    bf, f32 = jnp.bfloat16, jnp.float32
-    return {
-        "embed": ((vp, d), bf, STD),
-        "final_norm/scale": ((d,), f32, 0.0),
-        "lm_head": ((d, vp), bf, STD),
-        "blocks/dense/wqkv": ((L, d, (hq + 2 * hkv) * hd), bf, STD),
-        "blocks/dense/wo": ((L, hq * hd, d), bf, out_std),
-        "blocks/dense/mlp/wi": ((L, d, f), bf, STD),
-        "blocks/dense/mlp/wo": ((L, f, d), bf, out_std),
-        "blocks/dense/ln1/scale": ((L, d), f32, 0.0),
-        "blocks/dense/ln2/scale": ((L, d), f32, 0.0),
-    }
-
-
 def _leaf_key(key, path: str):
     return jax.random.fold_in(key, zlib.crc32(path.encode()) % 2**31)
 
@@ -67,9 +47,10 @@ def _draw(key, shape, dtype, std):
     return (jax.random.normal(key, shape, jnp.float32) * std).astype(dtype)
 
 
-def make_leaf(key, cfg, path: str, layer=None):
-    """One leaf (or one layer of a stacked leaf) of the seeded params."""
-    shape, dtype, std = leaf_table(cfg)[path]
+def make_leaf(fam, key, cfg, path: str, layer=None):
+    """One leaf (or one layer of a stacked leaf) of the seeded params of
+    the family ``fam``."""
+    shape, dtype, std = fam.leaf_table(cfg)[path]
     k = _leaf_key(key, path)
     if path.startswith("blocks/"):
         if layer is None:
@@ -82,6 +63,16 @@ def make_leaf(key, cfg, path: str, layer=None):
     if path == "embed" and shape[0] > cfg.vocab:
         out = out.at[cfg.vocab:].set(0)
     return out
+
+
+def stack_depth(fam, cfg) -> int:
+    """How many units the family's ``blocks/`` leaves stack on their
+    first axis; every such leaf has to stack as many."""
+    depths = {shape[0] for path, (shape, _, _) in fam.leaf_table(cfg).items()
+              if path.startswith("blocks/")}
+    if len(depths) != 1:
+        raise ValueError(f"blocks/ leaves stack {sorted(depths)} units")
+    return depths.pop()
 
 
 def nest(flat: Dict[str, Any]) -> Dict[str, Any]:
@@ -106,13 +97,14 @@ def flatten(tree, prefix: str = "") -> Dict[str, Any]:
     return out
 
 
-def make_params(cfg, seed: int, out_shardings=None):
+def make_params(fam, cfg, seed: int, out_shardings=None):
     """All params in one jitted call on the device."""
     key = base_key(seed)
 
     @functools.partial(jax.jit, out_shardings=out_shardings)
     def gen(key):
-        return nest({p: make_leaf(key, cfg, p) for p in leaf_table(cfg)})
+        return nest({p: make_leaf(fam, key, cfg, p)
+                     for p in fam.leaf_table(cfg)})
 
     return gen(key)
 

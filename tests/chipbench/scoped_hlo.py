@@ -63,12 +63,12 @@ def train_step_hlo(cell, sharding=None, backend: str = "auto") -> str:
     import jax
     import jax.numpy as jnp
 
-    from chipbench import spec, train as T
+    from chipbench import train as T
     from repro.models import init_params
     from repro.optim import AdamWConfig, init_opt_state
     from repro.train.train_step import TrainConfig, make_train_step
 
-    cfg, job = spec.arch_config(cell.config), cell.traffic
+    cfg, job = cell.family.arch_config(cell.config), cell.traffic
     pol = T.policy_of(job)
     pol = pol.replace(**{k: getattr(pol, k).replace(backend=backend)
                          for k in ("act", "weight", "grad")})

@@ -90,16 +90,18 @@ def test_serve_sound_run_is_correct(cpu_devices):
 
 
 def test_serve_control_fails(cpu_devices):
-    from chipbench import check, reference, spec
+    from chipbench import check, reference
 
     cell = tiny_cell("serve", SERVE_LIMITS)
-    cfg = spec.arch_config(cell.config)
+    cfg = cell.family.arch_config(cell.config)
     rng = np.random.default_rng(0)
     seqs = [rng.integers(0, cfg.vocab, 40).astype(np.int32)
             for _ in range(4)]
     picks = [np.arange(10, 40) for _ in seqs]
-    ref = reference.serve_logits(cfg, 3, seqs, picks, pad_to=256)
-    ctl = reference.serve_logits(cfg, 3, seqs, picks, bits=4, pad_to=256)
+    fam = cell.family
+    ref = reference.serve_logits(fam, cfg, 3, seqs, picks, pad_to=256)
+    ctl = reference.serve_logits(fam, cfg, 3, seqs, picks, bits=4,
+                                 pad_to=256)
     nums = check.serve_numbers([c.argmax(-1) for c in ctl], ref)
     ok, table = check.judge(nums, cell.limits)
     assert not ok, table

@@ -6,32 +6,31 @@ import pytest
 from chipbench_tiny import ROOT
 
 
-def _cfg(name):
+def _fam_cfg(name):
+    """The configuration's family file and its ArchConfig."""
     from chipbench import spec
 
-    return spec.arch_config(json.loads(
-        (ROOT / "chipbench" / "configs" / f"{name}.json").read_text()))
+    conf = json.loads(
+        (ROOT / "chipbench" / "configs" / f"{name}.json").read_text())
+    fam = spec.family_of(conf)
+    return fam, fam.arch_config(conf)
 
 
 def test_matmul_params_nemotron_l2():
-    from chipbench import counts
-
-    cfg = _cfg("nemotron3-8b-l2")
+    fam, cfg = _fam_cfg("nemotron3-8b-l2")
     per_layer = 4096 * 3 * 4096 + 4096 * 4096 + 2 * 4096 * 16384
-    assert counts.matmul_params_per_layer(cfg) == per_layer == 201_326_592
+    assert fam.matmul_params_per_layer(cfg) == per_layer == 201_326_592
     # Two layers and the 32,000-column head; the embedding is a lookup.
-    assert counts.matmul_params(cfg) == 2 * per_layer + 4096 * 32000
-    assert counts.matmul_params(cfg) == 533_725_184
+    assert fam.matmul_params(cfg) == 2 * per_layer + 4096 * 32000
+    assert fam.matmul_params(cfg) == 533_725_184
 
 
 def test_train_step_flops_hand_count():
-    from chipbench import counts
-
-    cfg = _cfg("nemotron3-8b-l2")
+    fam, cfg = _fam_cfg("nemotron3-8b-l2")
     tokens = 4 * 2048
     attn = 4 * 2 * (2 * 2048 * 2048 * 32 * 128)  # batch, layers, fwd
     want = 3 * (2 * 533_725_184 * tokens + attn)
-    assert counts.train_step_flops(cfg, 4, 2048) == pytest.approx(want)
+    assert fam.train_step_flops(cfg, 4, 2048) == pytest.approx(want)
     # A 482.5 ms step, as measured on a TPU v5e, at one chip's bf16 peak.
     mfu = want / 0.4825 / 197e12
     assert 0.28 < mfu < 0.29
@@ -55,11 +54,9 @@ def test_mixed_gemm_bytes():
 
 
 def test_decode_step_flops():
-    from chipbench import counts
-
-    cfg = _cfg("minitron-4b")
-    got = counts.decode_step_flops(cfg, [100, 300])
-    mm = 2 * counts.matmul_params(cfg) * 2
+    fam, cfg = _fam_cfg("minitron-4b")
+    got = fam.decode_step_flops(cfg, [100, 300])
+    mm = 2 * fam.matmul_params(cfg) * 2
     attn = 4 * 32 * 24 * 128 * (100 + 300)
     assert got == mm + attn
 

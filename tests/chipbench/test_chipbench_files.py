@@ -48,7 +48,8 @@ def test_config_maps_onto_the_program(cpu_devices):
     from chipbench import spec
 
     for conf in BENCH["configs"]:
-        cfg = spec.arch_config(json.loads((ROOT / conf["file"]).read_text()))
+        c = json.loads((ROOT / conf["file"]).read_text())
+        cfg = spec.family_of(c).arch_config(c)
         assert cfg.n_layers >= 1 and cfg.d_model % cfg.head_dim == 0
 
 

@@ -56,18 +56,20 @@ def test_schedule_lengths_stay_in_their_bounds():
 def test_seeded_weights_beyond_32_bits_are_reproducible(cpu_devices):
     import jax
 
-    from chipbench import spec, weights
+    from chipbench import weights
     from chipbench_tiny import tiny_cell
 
-    cfg = spec.arch_config(tiny_cell("train").config)
-    a = weights.make_params(cfg, 2**33 + 5)
-    b = weights.make_params(cfg, 2**33 + 5)
-    c = weights.make_params(cfg, 5)
+    cell = tiny_cell("train")
+    fam = cell.family
+    cfg = fam.arch_config(cell.config)
+    a = weights.make_params(fam, cfg, 2**33 + 5)
+    b = weights.make_params(fam, cfg, 2**33 + 5)
+    c = weights.make_params(fam, cfg, 5)
     weights.check_tree(cfg, a)
     same = jax.tree.map(lambda x, y: bool((x == y).all()), a, b)
     assert all(jax.tree.leaves(same))
     assert not bool((a["lm_head"] == c["lm_head"]).all())
-    one = weights.make_leaf(weights.base_key(2**33 + 5), cfg,
+    one = weights.make_leaf(fam, weights.base_key(2**33 + 5), cfg,
                             "blocks/dense/mlp/wi", 1)
     assert bool((one == a["blocks"]["dense"]["mlp"]["wi"][1]).all())
 

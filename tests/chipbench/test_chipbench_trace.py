@@ -82,19 +82,19 @@ def _decode_trace():
 
 
 def test_decode_readers_pick_the_decode_program():
-    from chipbench import counts, metrics, spec
+    from chipbench import counts, metrics
     from chipbench_tiny import file_cell
 
     cell = file_cell("minitron-4b", "chat_overload")
     lens = [[100] * 10, [300] * 12]
-    ctx = metrics.Ctx(cell=cell, cfg=spec.arch_config(cell.config),
+    ctx = metrics.Ctx(cell=cell, cfg=cell.family.arch_config(cell.config),
                       trace=_decode_trace(),
                       counters={"traced_calls": [("prefill", None)] +
                                 [("decode", l) for l in lens]},
                       peaks=counts.peaks("TPU v5 lite"), chips=1)
     assert metrics.reader("decode_step_ms")(ctx) == pytest.approx(11.0)
-    flops = (counts.decode_step_flops(ctx.cfg, lens[0])
-             + counts.decode_step_flops(ctx.cfg, lens[1])) / 2
+    count = cell.family.decode_step_flops
+    flops = (count(ctx.cfg, lens[0]) + count(ctx.cfg, lens[1])) / 2
     mfu = metrics.reader("decode_step_mfu")(ctx)
     assert mfu == pytest.approx(100 * flops / 0.011 / 197e12)
     share = metrics.reader("mixed_gemm_roofline")(ctx)

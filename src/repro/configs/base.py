@@ -55,10 +55,30 @@ class ArchConfig:
     act: str = "swiglu"  # 'swiglu' | 'geglu' | 'gelu' | 'relu2'
     norm: str = "rms"  # 'rms' | 'ln'
     rope_theta: float = 10000.0
-    # MoE
+    norm_eps: float = 1e-6  # RMS norm epsilon
+    # Multi-head latent attention (DeepSeek-V2/V3), on when kv_lora_rank
+    # > 0: queries x @ wq give n_heads x (qk_nope_dim + qk_rope_dim); the
+    # latent x @ wkva gives kv_lora_rank RMS-normed columns, up-projected
+    # to n_heads x (qk_nope_dim + head_dim) keys and values, and
+    # qk_rope_dim rotary key columns shared by every head. head_dim is
+    # the value width.
+    kv_lora_rank: int = 0
+    qk_nope_dim: int = 0
+    qk_rope_dim: int = 0
+    # Leading dense layers (attn + an MLP of d_ff) before the repeating
+    # unit; they count in n_layers.
+    n_lead: int = 0
+    # MoE. n_experts is the router's width (every expert of the layer);
+    # this program holds held_experts of them (0: all), from first_expert
+    # on, and computes only their part of the layer's output.
     n_experts: int = 0
     top_k: int = 0
-    capacity_factor: float = 1.25
+    moe_d_ff: int = 0  # expert width (0: d_ff)
+    n_shared_experts: int = 0  # always-on experts, one MLP of n * moe_d_ff
+    score_func: str = "softmax"  # 'softmax' | 'sigmoid'
+    routed_scale: float = 1.0  # multiplies the routed experts' gates
+    held_experts: int = 0
+    first_expert: int = 0
     # SSM / hybrid
     ssm_state: int = 0
     d_inner: int = 0  # mamba inner dim (0 => 2*d_model)
@@ -77,7 +97,25 @@ class ArchConfig:
 
     @property
     def n_units(self) -> int:
-        return self.n_layers // len(self.unit)
+        return (self.n_layers - self.n_lead) // len(self.unit)
+
+    @property
+    def mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def qk_head_dim(self) -> int:
+        """Width of a query/key head: nope + rope columns under MLA."""
+        return self.qk_nope_dim + self.qk_rope_dim if self.mla \
+            else self.head_dim
+
+    @property
+    def expert_d_ff(self) -> int:
+        return self.moe_d_ff or self.d_ff
+
+    @property
+    def n_held(self) -> int:
+        return self.held_experts or self.n_experts
 
     @property
     def mamba_d_inner(self) -> int:
@@ -88,13 +126,18 @@ class ArchConfig:
         d, f, v = self.d_model, self.d_ff, self.vocab
         hq, hkv, hd = self.n_heads, self.n_kv, self.head_dim
         per_type = {}
-        attn = d * (hq + 2 * hkv) * hd + hq * hd * d
+        if self.mla:
+            r, dq = self.kv_lora_rank, self.qk_head_dim
+            attn = (d * hq * dq + d * (r + self.qk_rope_dim) + r
+                    + r * hq * (self.qk_nope_dim + hd) + hq * hd * d)
+        else:
+            attn = d * (hq + 2 * hkv) * hd + hq * hd * d
         gated = self.act in ("swiglu", "geglu")
         mlp = d * f * (3 if gated else 2)
         per_type["dense"] = attn + mlp
-        per_type["moe"] = attn + self.n_experts * d * f * (
-            3 if gated else 2
-        ) + d * self.n_experts
+        fe = self.expert_d_ff * (3 if gated else 2) * d
+        per_type["moe"] = (attn + self.n_held * fe + d * self.n_experts
+                           + self.n_shared_experts * fe)
         di = self.mamba_d_inner
         per_type["hymba"] = (
             attn + mlp + 2 * d * di + di * d
@@ -105,6 +148,7 @@ class ArchConfig:
         total = 0
         for t in self.unit:
             total += per_type.get(t, per_type["dense"]) * self.n_units
+        total += self.n_lead * per_type["dense"]
         total += v * d * (1 if self.tie_embed else 2)
         total += self.enc_layers * (attn + mlp)
         return total
@@ -113,9 +157,9 @@ class ArchConfig:
         """Active params per token (MoE: top_k of n_experts)."""
         if self.n_experts == 0:
             return self.param_count()
-        d, f = self.d_model, self.d_ff
+        d, f = self.d_model, self.expert_d_ff
         gated = self.act in ("swiglu", "geglu")
-        dense_experts = self.n_experts * d * f * (3 if gated else 2)
+        dense_experts = self.n_held * d * f * (3 if gated else 2)
         active_experts = self.top_k * d * f * (3 if gated else 2)
         return self.param_count() - (
             dense_experts - active_experts
@@ -125,7 +169,7 @@ class ArchConfig:
 _REGISTRY: Dict[str, ArchConfig] = {}
 
 _ARCH_MODULES = [
-    "moonshot_v1_16b_a3b", "granite_moe_1b_a400m", "gemma_2b",
+    "moonlight_16b_a3b", "granite_moe_1b_a400m", "gemma_2b",
     "deepseek_coder_33b", "llama3_8b", "minitron_4b", "whisper_tiny",
     "xlstm_350m", "paligemma_3b", "hymba_1_5b", "nemotron3_8b",
 ]
@@ -163,11 +207,16 @@ def cell_is_runnable(cfg: ArchConfig, shape: ShapeConfig) -> Tuple[bool, str]:
 
 def reduced(cfg: ArchConfig) -> ArchConfig:
     """CPU-smoke-scale downscale preserving the family's structure."""
-    kv = 1 if cfg.n_kv == 1 else 2
+    # MLA keeps one key/value head per query head.
+    kv = 4 if cfg.mla else 1 if cfg.n_kv == 1 else 2
+    mla = {}
+    if cfg.mla:
+        mla = dict(kv_lora_rank=32, qk_nope_dim=16, qk_rope_dim=8)
+    n_lead = min(cfg.n_lead, 1)
     return dataclasses.replace(
         cfg,
         name=cfg.name + "-reduced",
-        n_layers=2 * len(cfg.unit) if len(cfg.unit) > 1 else 2,
+        n_layers=n_lead + (2 * len(cfg.unit) if len(cfg.unit) > 1 else 2),
         d_model=64,
         n_heads=4,
         n_kv=kv,
@@ -176,6 +225,11 @@ def reduced(cfg: ArchConfig) -> ArchConfig:
         vocab=512,
         n_experts=min(cfg.n_experts, 4),
         top_k=min(cfg.top_k, 2),
+        moe_d_ff=min(cfg.moe_d_ff, 32),
+        held_experts=min(cfg.held_experts, 4),
+        first_expert=0,
+        n_lead=n_lead,
+        **mla,
         ssm_state=min(cfg.ssm_state, 8) if cfg.ssm_state else 0,
         d_inner=128 if cfg.family in ("hybrid",) else 0,
         enc_layers=min(cfg.enc_layers, 2),

@@ -29,7 +29,7 @@ from repro.core.partition import Partition, _pad2d
 from . import ref as _ref
 from .flash_attention import flash_attention_fwd
 from .fp8_gemm import fp8_gemm as _fp8_gemm_kernel
-from .gam_quant import gam_quant_blocks
+from .gam_quant import gam_quant_blocks, gam_quant_groups
 from .mixed_gemm import (
     DECODE_CACHE_BUDGET,
     decode_cache_bytes,
@@ -41,6 +41,8 @@ from .ref import MixedOperand, MorSelect, QuantErr
 __all__ = [
     "gam_quant",
     "quant_err",
+    "quant_err_groups",
+    "quantize_groups",
     "mor_select",
     "quantize_pack",
     "fp8_gemm",
@@ -165,6 +167,80 @@ def quant_err(
         group_amax=g_amax,
         group_mantissa=m_g,
     )
+
+
+def quant_err_groups(
+    x: jnp.ndarray,
+    part: Partition,
+    fmt: FormatSpec,
+    algo: str,
+    row_amax: jnp.ndarray,
+    live_rows: jnp.ndarray,
+    *,
+    backend: str = "auto",
+):
+    """The error pass of :func:`quant_err` over an operand whose rows
+    hold several tensors, each starting on a block row.
+
+    ``x`` (M, K) has M a whole multiple of the block rows (K is padded
+    with zero columns, as in :func:`quant_err`); ``row_amax`` (nm,) is
+    the amax of the group (tensor) each block row belongs to and
+    ``live_rows`` () how many block rows from the first hold a group.
+    The kernel reads only those, and writes no rows. Returns (err_sums
+    (nm, nk), counts (nm, nk), group mantissa of each block row (nm,)),
+    the sums zero past the live rows.
+    """
+    be = resolve_backend(backend)
+    bm, bk = part.resolve(x.shape)
+    xp = _pad2d(x, bm, bk)  # zero columns: no error, no count
+    if be == "xla":
+        _, err, cnt, m_g = _ref.quant_err_groups_ref(
+            xp, part, fmt, algo, row_amax
+        )
+        return err, cnt, m_g
+    m_g = _row_mantissa(row_amax, fmt, algo)
+    err, cnt = gam_quant_groups(
+        xp, m_g, live_rows,
+        block=(bm, bk), q_amax=fmt.amax, fmt_dtype=fmt.dtype,
+        algo=algo, interpret=(be == "interpret"),
+    )
+    return err, cnt, m_g
+
+
+def quantize_groups(
+    x: jnp.ndarray,
+    part: Partition,
+    fmt: FormatSpec,
+    algo: str,
+    row_amax: jnp.ndarray,
+    keep: jnp.ndarray,
+    live_rows: jnp.ndarray,
+    *,
+    backend: str = "auto",
+) -> jnp.ndarray:
+    """The apply pass after :func:`quant_err_groups`: ``x`` with each
+    block row fake-quantized (scaled by its group's amax) where ``keep``
+    (nm,) is set and left as it is elsewhere. The kernel writes over
+    ``x`` in place and only its live rows."""
+    be = resolve_backend(backend)
+    K = x.shape[1]
+    bm, bk = part.resolve(x.shape)
+    xp = _pad2d(x, bm, bk)
+    if be == "xla":
+        y = _ref.quant_err_groups_ref(xp, part, fmt, algo, row_amax)[0]
+        rows = jnp.repeat(keep, bm, total_repeat_length=xp.shape[0])
+        return jnp.where(rows[:, None], y, xp)[:, :K]
+    y = gam_quant_groups(
+        xp, _row_mantissa(row_amax, fmt, algo), live_rows, keep,
+        block=(bm, bk), q_amax=fmt.amax, fmt_dtype=fmt.dtype,
+        algo=algo, interpret=(be == "interpret"),
+    )
+    return y[:, :K]
+
+
+def _row_mantissa(row_amax, fmt: FormatSpec, algo: str):
+    safe = jnp.where((row_amax > 0) & jnp.isfinite(row_amax), row_amax, 1.0)
+    return jnp.broadcast_to(_group_mantissa(safe, fmt, algo), safe.shape)
 
 
 def _select_kernel_call(x, block, mode, algo, emit, be, mesh_axes):

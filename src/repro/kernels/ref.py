@@ -641,6 +641,21 @@ def quant_err_ref(
     )
 
 
+def quant_err_groups_ref(
+    x: jnp.ndarray, part: Partition, fmt: FormatSpec, algo: str,
+    row_amax: jnp.ndarray,
+):
+    """Reference for the grouped one-format event (``ops.quant_err_groups``):
+    each block row scaled with its own group's amax ``row_amax`` (nm,).
+    Returns (y, err_sums (nm, nk), counts (nm, nk), mantissa (nm,))."""
+    xb = to_blocks(x, part)
+    xqb, scales, err_sums, counts = _blocked_quant_err(
+        xb, fmt, algo, group_amax=row_amax[:, None]
+    )
+    m_g = jnp.broadcast_to(scales.group_mantissa, row_amax.shape[:1] + (1,))
+    return from_blocks(xqb, x.shape), err_sums, counts, m_g[:, 0]
+
+
 def mor_select_ref(
     x: jnp.ndarray, part: Partition, mode: str = "sub3", algo: str = "gam",
     mesh_axes=(),

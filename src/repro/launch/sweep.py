@@ -34,7 +34,7 @@ ARCHS_BY_COST = [
     "minitron-4b",
     "paligemma-3b",
     "llama3-8b",
-    "moonshot-v1-16b-a3b",
+    "moonlight-16b-a3b",
     "deepseek-coder-33b",
 ]
 SHAPES_BY_COST = ["decode_32k", "long_500k", "prefill_32k", "train_4k"]

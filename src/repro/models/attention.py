@@ -80,12 +80,14 @@ def flash_attention(
     q_chunk: int = 512,
     k_chunk: int = 1024,
 ) -> jnp.ndarray:
-    """q: (B, S, Hq, dh); k, v: (B, T, Hkv, dh) with Hq % Hkv == 0.
+    """q, k: (B, S|T, Hq|Hkv, dh); v: (B, T, Hkv, dv) with Hq % Hkv == 0
+    (dv == dh but for latent attention). Scores scale by dh**-0.5.
 
-    Returns (B, S, Hq, dh) in q.dtype. Softmax in f32.
+    Returns (B, S, Hq, dv) in q.dtype. Softmax in f32.
     """
     B, S, Hq, dh = q.shape
     T, Hkv = k.shape[1], k.shape[2]
+    dv = v.shape[-1]
     G = Hq // Hkv
     qc = pick_chunk(S, q_chunk)
     kc = pick_chunk(T, k_chunk)
@@ -95,7 +97,7 @@ def flash_attention(
     qg = (q.astype(jnp.float32) * scale).reshape(B, nq, qc, Hkv, G, dh)
     qg = jnp.moveaxis(qg, 1, 0)  # (nq, B, qc, Hkv, G, dh)
     kcs = jnp.moveaxis(k.reshape(B, nk, kc, Hkv, dh), 1, 0)
-    vcs = jnp.moveaxis(v.reshape(B, nk, kc, Hkv, dh), 1, 0)
+    vcs = jnp.moveaxis(v.reshape(B, nk, kc, Hkv, dv), 1, 0)
 
     # Sliding-window: each q chunk only sees a static-size band of kv
     # chunks (dynamic start). Without this the scan visits all nk chunks
@@ -111,7 +113,7 @@ def flash_attention(
 
         m0 = jnp.full((B, Hkv, G, qc), _NEG, jnp.float32)
         l0 = jnp.zeros((B, Hkv, G, qc), jnp.float32)
-        a0 = jnp.zeros((B, Hkv, G, qc, dh), jnp.float32)
+        a0 = jnp.zeros((B, Hkv, G, qc, dv), jnp.float32)
 
         if band < nk:
             start = jnp.clip(
@@ -154,7 +156,7 @@ def flash_attention(
         return jnp.moveaxis(out, 3, 1)  # (B, qc, Hkv, G, dh)
 
     outs = jax.lax.map(q_chunk_fn, (jnp.arange(nq), qg))
-    out = jnp.moveaxis(outs, 0, 1).reshape(B, S, Hq, dh)
+    out = jnp.moveaxis(outs, 0, 1).reshape(B, S, Hq, dv)
     return out.astype(q.dtype)
 
 
@@ -286,7 +288,7 @@ def decode_attention(
         k_any = k_pos[None, :] <= cur[:, None]  # (B, T)
         vv = jnp.where(k_any[:, :, None, None], vv, 0.0)
     out = jnp.einsum("bhgsk,bkhd->bshgd", p, vv)
-    return out.reshape(B, S, Hq, dh).astype(q.dtype)
+    return out.reshape(B, S, Hq, vv.shape[-1]).astype(q.dtype)
 
 
 def quantize_kv(x: jnp.ndarray):

@@ -5,9 +5,11 @@ MoE expert FFNs) goes through :func:`repro.core.mor_dot`; routers, norms and
 embeddings stay BF16, matching the paper's policy.
 
 Named scopes mark the layers in the compiled program: ``norm``,
-``residual``, ``attn/{qkv,rope,core,proj}`` and ``mlp/{fc1,act,fc2}``
-here, ``mor_quant/<role>`` and ``gemm/<which>`` inside each linear
-(``repro.core.linear``), so a device trace can be read per layer.
+``residual``, ``attn/{qkv,rope,core,proj}`` (latent attention adds
+``attn/latent``), ``mlp/{fc1,act,fc2}`` and, for an MoE layer,
+``mlp/moe/{route,dispatch,experts,shared,combine}`` here, ``mor_quant/<role>``
+and ``gemm/<which>`` inside each linear (``repro.core.linear``,
+``repro.core.grouped``), so a device trace can be read per layer.
 
 Block functions share the signature
     f(p, x, tok, policy, cfg, mode, cache, cur_index) -> (x, cache, stats)
@@ -22,6 +24,9 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import MoRDotPolicy, mor_dot
+from repro.core.grouped import (
+    grouped_ffn, make_groups, over_live_rows, row_align,
+)
 from repro.configs.base import ArchConfig
 
 from .attention import decode_attention, flash_attention
@@ -31,13 +36,12 @@ from .common import (
     constrain,
     glu_split,
     layer_norm,
-    pick_chunk,
     rms_norm,
 )
 
 __all__ = [
-    "norm", "residual", "attn_sublayer", "mlp_sublayer", "moe_sublayer",
-    "dense_block", "moe_block",
+    "norm", "residual", "attn_sublayer", "mla_sublayer", "mlp_sublayer",
+    "moe_sublayer", "dense_block", "moe_block",
 ]
 
 
@@ -45,7 +49,7 @@ __all__ = [
 def norm(p_norm, x, cfg: ArchConfig):
     if cfg.norm == "ln":
         return layer_norm(x, p_norm["scale"], p_norm["bias"])
-    return rms_norm(x, p_norm["scale"])
+    return rms_norm(x, p_norm["scale"], cfg.norm_eps)
 
 
 @jax.named_scope("residual")
@@ -83,7 +87,11 @@ def attn_sublayer(
     window: int = 0,
     use_rope: bool = True,
 ):
-    """Self-attention with GQA + RoPE + KV cache. Returns (y, cache, stats)."""
+    """Self-attention with GQA + RoPE + KV cache. Returns (y, cache, stats).
+    Under ``cfg.mla`` the latent attention of :func:`mla_sublayer`."""
+    if cfg.mla:
+        return mla_sublayer(p, xn, tok, policy, cfg, mode, cache, cur_index,
+                            kind=kind, prefix_len=prefix_len, window=window)
     B, S, _ = xn.shape
     with jax.named_scope("qkv"):
         qkv, st_qkv = mor_dot(xn, p["wqkv"], tok["qkv"], policy)
@@ -131,6 +139,64 @@ def attn_sublayer(
 @jax.named_scope("rope")
 def _rope(q, k, pos, theta: float):
     return apply_rope(q, pos, theta), apply_rope(k, pos, theta)
+
+
+def mla_sublayer(p, xn, tok, policy: MoRDotPolicy, cfg: ArchConfig,
+                 mode: str, cache, cur_index, *, kind: str = "causal",
+                 prefix_len: int = 0, window: int = 0):
+    """Multi-head latent attention (DeepSeek-V2/V3) without a query
+    low-rank: q = x wq (heads of nope + rope columns); the latent x wkva
+    gives ``kv_lora_rank`` columns, RMS-normed and up-projected by wkvb
+    to each head's nope key and value, and ``qk_rope_dim`` rotary key
+    columns shared by every head. Scores scale by the query width**-0.5.
+    Runs under the caller's ``attn`` scope. Returns (y, cache, stats);
+    the cache holds the heads' keys and values, as for GQA."""
+    B, S, _ = xn.shape
+    H, dn, dr = cfg.n_heads, cfg.qk_nope_dim, cfg.qk_rope_dim
+    dv, r = cfg.head_dim, cfg.kv_lora_rank
+    with jax.named_scope("qkv"):
+        q, st_q = mor_dot(xn, p["wq"], tok["q"], policy)
+        lat, st_kva = mor_dot(xn, p["wkva"], tok["kva"], policy)
+        q = q.reshape(B, S, H, dn + dr)
+        c, k_rope = jnp.split(lat, [r], axis=-1)
+    with jax.named_scope("latent"):
+        c = rms_norm(c, p["kv_norm"]["scale"], cfg.norm_eps)
+    with jax.named_scope("qkv"):
+        kv, st_kvb = mor_dot(c, p["wkvb"], tok["kvb"], policy)
+        k_nope, v = jnp.split(kv.reshape(B, S, H, dn + dv), [dn], axis=-1)
+
+    if mode == "decode":
+        cur = jnp.broadcast_to(
+            jnp.atleast_1d(jnp.asarray(cur_index, jnp.int32)), (B,)
+        )
+        pos = cur[:, None] - (S - 1) + jnp.arange(S, dtype=jnp.int32)[None]
+    else:
+        pos = jnp.arange(S, dtype=jnp.int32)[None]
+    q_rope, k_rope = _rope(q[..., dn:], k_rope[:, :, None, :], pos,
+                           cfg.rope_theta)
+    with jax.named_scope("latent"):
+        q = jnp.concatenate([q[..., :dn], q_rope], axis=-1)
+        k = jnp.concatenate(
+            [k_nope, jnp.broadcast_to(k_rope, (B, S, H, dr))], axis=-1)
+
+    if mode == "decode":
+        out, new_cache = _decode_core(q, k, v, cache, cur, pos, window)
+    else:
+        with jax.named_scope("core"):
+            out = flash_attention(
+                q, k, v, kind=kind, prefix_len=prefix_len, window=window
+            )
+        new_cache = (
+            {"k": k.astype(jnp.bfloat16), "v": v.astype(jnp.bfloat16)}
+            if mode == "prefill"
+            else None
+        )
+
+    with jax.named_scope("proj"):
+        out = out.reshape(B, S, H * dv)
+        y, st_proj = mor_dot(out, p["wo"], tok["proj"], policy)
+    return y, new_cache, {"q": st_q, "kva": st_kva, "kvb": st_kvb,
+                          "proj": st_proj}
 
 
 @jax.named_scope("core")
@@ -204,89 +270,184 @@ def mlp_sublayer(p, xn, tok, policy: MoRDotPolicy, cfg: ArchConfig,
 
 
 # -------------------------------------------------------------------- MoE --
-def moe_sublayer(p, xn, tok, policy: MoRDotPolicy, cfg: ArchConfig):
-    """Capacity-based MoE with per-(example, chunk) grouping.
+def _route(p, x, cfg: ArchConfig, B: int, S: int):
+    """Scores over all ``n_experts`` in f32, the chosen experts (T, K),
+    their gates, and the sequence-wise balance loss.
 
-    Tokens are chunked along the sequence axis (scan => bounded transients);
-    each (example, chunk) group dispatches into an (E, C, d) buffer via
-    one-hot einsums (GSPMD-friendly: group dim rides the data axis, expert
-    dim rides the model axis). Expert FFN GEMMs are MoR-quantized per
-    expert via vmap(mor_dot).
+    Selection takes the top-k of the scores plus the bias (DeepSeek-V3's
+    aux-loss-free bias: it picks experts and weighs nothing, and gets no
+    gradient); the gates are the chosen scores, renormalised to sum 1
+    and scaled by ``routed_scale``. The balance loss
+    is, per sequence, sum_i f_i P_i with f_i = E / (K S) times the
+    copies sent to expert i and P_i the mean of its score normalised
+    over the experts; the mean over sequences is returned."""
+    E, K = cfg.n_experts, cfg.top_k
+    logits = jnp.dot(x.astype(jnp.float32), p["router"],
+                     precision=jax.lax.Precision.HIGHEST)
+    if cfg.score_func == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+    else:
+        scores = jax.nn.softmax(logits, axis=-1)
+    _, idx = jax.lax.top_k(scores + jax.lax.stop_gradient(p["bias"]), K)
+    gate = jnp.take_along_axis(scores, idx, axis=-1)
+    gate = gate / (jnp.sum(gate, axis=-1, keepdims=True) + 1e-20)
+    gate = gate * cfg.routed_scale
+
+    sel = jnp.sum(jax.nn.one_hot(idx.reshape(B, S * K), E,
+                                 dtype=jnp.float32), axis=1)  # (B, E)
+    f = sel * (E / (K * S))
+    share = scores / jnp.sum(scores, axis=-1, keepdims=True)
+    aux = jnp.mean(jnp.sum(f * jnp.mean(share.reshape(B, S, E), 1), -1))
+    return idx, gate, aux
+
+
+def _dispatch(idx, cfg: ArchConfig, T: int, align: int):
+    """Lays the token-copies that chose a held expert out by expert.
+
+    Returns (Groups, ``src`` (R,) the token of each buffer row, T for
+    padding and the tail, ``dest`` (T, K) the buffer row of each copy, R
+    for a copy of an expert held elsewhere). R = T * min(K, held) rows
+    plus a block of padding per held expert, a static bound that every
+    routing fits."""
+    K, H = cfg.top_k, cfg.n_held
+    R = -(-(T * min(K, H) + H * (align - 1)) // align) * align
+    local = idx.reshape(T * K) - cfg.first_expert
+    key = jnp.where((local >= 0) & (local < H), local, H)
+    rows = jnp.bincount(key, length=H + 1)[:H]
+    groups = make_groups(rows, align)
+    order = jnp.argsort(key, stable=True)
+    ks = key[order]
+    first = jnp.cumsum(rows) - rows  # each group's first sorted rank
+    offset = jnp.cumsum(groups.sizes) - groups.sizes
+    rank = jnp.arange(T * K, dtype=jnp.int32)
+    held = ks < H
+    at = jnp.where(held, offset[jnp.minimum(ks, H - 1)] + rank
+                   - first[jnp.minimum(ks, H - 1)], R)
+    dest = jnp.zeros((T * K,), jnp.int32).at[order].set(at.astype(jnp.int32))
+    src = jnp.full((R + 1,), T, jnp.int32).at[dest].set(
+        rank // K, mode="drop")[:R]
+    return groups, src, dest.reshape(T, K)
+
+
+def _rows_of(x, src, live):
+    """(T, d) tokens -> (R, d) buffer: row r holds ``x[src[r]]``, zero
+    where ``src`` is T (padding) and past the live rows."""
+    buf = jnp.zeros((src.shape[0], x.shape[1]), x.dtype)
+    return over_live_rows(
+        lambda _, s: (x.at[s].get(mode="fill", fill_value=0),), live,
+        (buf,), src)[0]
+
+
+def _sum_copies(buf, dest):
+    """(R, d) buffer -> (T, d) f32: each token's sum over its copies'
+    rows, zero for a copy held elsewhere (``dest`` R)."""
+    y = 0.0
+    for k in range(dest.shape[1]):
+        y = y + buf.at[dest[:, k]].get(mode="fill",
+                                       fill_value=0).astype(jnp.float32)
+    return y
+
+
+# Dispatch and combine are each other's transposes: both backward passes
+# are gathers, never a scatter-add into the buffer.
+@jax.custom_vjp
+def _to_rows(x, src, dest, live):
+    return _rows_of(x, src, live)
+
+
+def _to_rows_fwd(x, src, dest, live):
+    return _rows_of(x, src, live), (src, dest)
+
+
+def _to_rows_bwd(res, ct):
+    src, dest = res
+    return _sum_copies(ct, dest).astype(ct.dtype), None, None, None
+
+
+_to_rows.defvjp(_to_rows_fwd, _to_rows_bwd)
+
+
+@jax.custom_vjp
+def _to_tokens(buf, src, dest, live):
+    return _sum_copies(buf, dest)
+
+
+def _to_tokens_fwd(buf, src, dest, live):
+    # An empty array carries the buffer's dtype to the backward pass.
+    return _sum_copies(buf, dest), (src, live, jnp.zeros((0,), buf.dtype))
+
+
+def _to_tokens_bwd(res, ct):
+    src, live, like = res
+    return _rows_of(ct.astype(like.dtype), src, live), None, None, None
+
+
+_to_tokens.defvjp(_to_tokens_fwd, _to_tokens_bwd)
+
+
+@jax.named_scope("mlp")
+@jax.named_scope("moe")
+def moe_sublayer(p, xn, tok, policy: MoRDotPolicy, cfg: ArchConfig):
+    """Dropless MoE over the experts this program holds.
+
+    The router scores all ``cfg.n_experts`` experts (``_route``); the
+    program holds ``cfg.n_held`` of them from ``cfg.first_expert`` on
+    (an expert-parallel shard) and computes every token-copy that chose
+    one of them, none dropped, and nothing of the others: their part of
+    the output belongs to the shards that hold them. The copies are laid
+    out by expert in one buffer of static bound (``_dispatch``), the
+    expert FFNs run as grouped MoR products over it
+    (``repro.core.grouped``: each expert's rows and weights are their
+    own MoR tensors; ``grouped_ffn``), the activation weighs each row by
+    its copy's gate, and each token sums its copies. The work of every
+    pass over the buffer, forward and backward, follows the routed rows:
+    the products and quantization stop at the last group, the gathers
+    into the buffer and the activation at the chunk that holds it, and
+    the sums read T * K rows. Shared experts, if any, run on every token
+    as one MLP. Scopes: ``moe/route``, ``moe/dispatch``, ``moe/experts``,
+    ``moe/shared``, ``moe/combine``, under the block's feed-forward
+    scope ``mlp`` (so that an op XLA hoists out of the layer scan still
+    names a layer).
+
+    Stats: the experts' MoR rows, ``aux_loss`` (the balance loss), and
+    the counters ``routed_rows`` (copies computed here), ``padded_rows``
+    (zero rows that align the groups) and ``load_max_over_mean`` (the
+    busiest held expert's rows over the mean).
     """
     B, S, d = xn.shape
-    E, K = cfg.n_experts, cfg.top_k
-    gated = cfg.act in ("swiglu", "geglu")
-    act_fn = activation(cfg.act)
-
-    s_sub = pick_chunk(S, 256)
-    n_sub = S // s_sub
-    C = max(1, int(K * s_sub / E * cfg.capacity_factor))
-
-    w1, w2, router = p["w1"], p["w2"], p["router"]
-    tok_w1, tok_w2 = tok["w1"], tok["w2"]
-
-    xc = xn.reshape(B, n_sub, s_sub, d)
-    xc = jnp.moveaxis(xc, 1, 0)  # (n_sub, B, s_sub, d)
-
-    def chunk_fn(_, x_c):
-        # x_c: (B, t, d)
-        logits = jnp.einsum(
-            "btd,de->bte", x_c, router, preferred_element_type=jnp.float32
-        )
-        probs = jax.nn.softmax(logits, axis=-1)
-        vals, idx = jax.lax.top_k(probs, K)  # (B, t, K)
-        vals = vals / jnp.maximum(
-            jnp.sum(vals, -1, keepdims=True), 1e-9
-        )
-        # Flatten the K token-copies.
-        t = x_c.shape[1]
-        ids = idx.reshape(B, t * K)
-        gate = vals.reshape(B, t * K)
-        oh = jax.nn.one_hot(ids, E, dtype=jnp.float32)  # (B, tK, E)
-        pos = jnp.cumsum(oh, axis=1) - oh
-        slot = jnp.sum(pos * oh, axis=-1)  # (B, tK)
-        keep = (slot < C).astype(jnp.float32)
-        slot_oh = jax.nn.one_hot(
-            jnp.minimum(slot, C - 1).astype(jnp.int32), C, dtype=jnp.float32
-        ) * keep[..., None]
-        x_rep = jnp.repeat(
-            x_c.astype(jnp.float32), K, axis=1
-        )  # (B, tK, d)
-
-        xbuf = jnp.einsum("bse,bsc,bsd->ebcd", oh, slot_oh, x_rep)
-        xbuf = constrain(xbuf, "model", "batch", None, None)
-        xbuf = xbuf.astype(xn.dtype)
-
-        h, st1 = jax.vmap(
-            lambda a, w, tk: mor_dot(a, w, tk, policy)
-        )(xbuf, w1, tok_w1)
-        h = glu_split(h, gated, act_fn)
-        ybuf, st2 = jax.vmap(
-            lambda a, w, tk: mor_dot(a, w, tk, policy)
-        )(h, w2, tok_w2)
-
-        y = jnp.einsum(
-            "bse,bsc,bs,ebcd->bsd",
-            oh, slot_oh, gate, ybuf.astype(jnp.float32),
-        )
-        y = y.reshape(B, t, K, d).sum(axis=2)
-
-        # Load-balance aux loss (Switch-style) + drop fraction.
-        me = jnp.mean(oh.reshape(B, t, K, E).sum(2), axis=(0, 1))
-        ce = jnp.mean(probs, axis=(0, 1))
-        aux = jnp.sum(me * ce) * E
-        dropped = 1.0 - jnp.mean(keep)
-        return None, (y.astype(xn.dtype), st1, st2, aux, dropped)
-
-    _, (ys, st1, st2, aux, dropped) = jax.lax.scan(chunk_fn, None, xc)
-    y = jnp.moveaxis(ys, 0, 1).reshape(B, S, d)
-    stats = {
-        "w1": jnp.mean(st1, axis=0),  # (E, 2, W) averaged over chunks
-        "w2": jnp.mean(st2, axis=0),
-        "aux_loss": jnp.mean(aux),
-        "dropped": jnp.mean(dropped),
-    }
-    return y, stats
+    T = B * S
+    x = xn.reshape(T, d)
+    with jax.named_scope("route"):
+        idx, gate, aux = _route(p, x, cfg, B, S)
+    with jax.named_scope("dispatch"):
+        groups, src, dest = _dispatch(idx, cfg, T, row_align(policy))
+        live = jnp.sum(groups.sizes)
+        xbuf = _to_rows(x, src, dest, live)
+        row_gate = jnp.zeros(src.shape, jnp.float32).at[
+            dest.reshape(-1)].set(gate.reshape(-1), mode="drop")
+    with jax.named_scope("experts"):
+        gated, act_fn = cfg.act in ("swiglu", "geglu"), activation(cfg.act)
+        ybuf, st1, st2 = grouped_ffn(
+            xbuf, p["w1"], p["w2"], row_gate, tok["w1"], tok["w2"], groups,
+            policy, lambda h: glu_split(h, gated, act_fn))
+    stats = {"w1": st1, "w2": st2}
+    with jax.named_scope("combine"):
+        y = _to_tokens(ybuf, src, dest, live)
+    if cfg.n_shared_experts:
+        with jax.named_scope("shared"):
+            ys, stats["shared"] = mlp_sublayer(p["shared"], x, tok["shared"],
+                                               policy, cfg)
+        y = y + ys.astype(jnp.float32)
+    rows = groups.rows.astype(jnp.float32)
+    stats.update(
+        aux_loss=aux,
+        routed_rows=jnp.sum(rows),
+        padded_rows=jnp.sum(groups.sizes).astype(jnp.float32)
+        - jnp.sum(rows),
+        load_max_over_mean=jnp.max(rows)
+        / jnp.maximum(jnp.mean(rows), 1.0),
+    )
+    return y.astype(xn.dtype).reshape(B, S, d), stats
 
 
 # ------------------------------------------------------------ full blocks --

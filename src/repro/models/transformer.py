@@ -60,8 +60,18 @@ def _ffin(cfg: ArchConfig, f: int) -> int:
 
 def _attn_params(key, cfg: ArchConfig):
     d, hq, hkv, hd = cfg.d_model, cfg.n_heads, cfg.n_kv, cfg.head_dim
-    k1, k2 = jax.random.split(key)
     depth_std = 0.02 / max(1.0, (2 * cfg.n_layers) ** 0.5)
+    if cfg.mla:
+        r, dn = cfg.kv_lora_rank, cfg.qk_nope_dim
+        k1, k2, k3, k4 = jax.random.split(key, 4)
+        return {
+            "wq": _lin(k1, (d, hq * cfg.qk_head_dim)),
+            "wkva": _lin(k2, (d, r + cfg.qk_rope_dim)),
+            "kv_norm": {"scale": jnp.zeros((r,), jnp.float32)},
+            "wkvb": _lin(k3, (r, hq * (dn + hd))),
+            "wo": _lin(k4, (hq * hd, d), std=depth_std),
+        }
+    k1, k2 = jax.random.split(key)
     return {
         "wqkv": _lin(k1, (d, (hq + 2 * hkv) * hd)),
         "wo": _lin(k2, (hq * hd, d), std=depth_std),
@@ -89,17 +99,22 @@ def _dense_layer(key, cfg: ArchConfig):
 
 
 def _moe_layer(key, cfg: ArchConfig):
-    ka, kr, k1, k2, kn = jax.random.split(key, 5)
-    E, d, f = cfg.n_experts, cfg.d_model, cfg.d_ff
+    ka, kr, k1, k2, ks, kn = jax.random.split(key, 6)
+    E, H, d, f = cfg.n_experts, cfg.n_held, cfg.d_model, cfg.expert_d_ff
     depth_std = 0.02 / max(1.0, (2 * cfg.n_layers) ** 0.5)
     p = _attn_params(ka, cfg)
     p["moe"] = {
         "router": (jax.random.normal(kr, (d, E), jnp.float32) * 0.02).astype(
             jnp.float32
         ),
-        "w1": _lin(k1, (E, d, _ffin(cfg, f))),
-        "w2": _lin(k2, (E, f, d), std=depth_std),
+        # Selection-only bias: zero at the start, no gradient.
+        "bias": jnp.zeros((E,), jnp.float32),
+        "w1": _lin(k1, (H, d, _ffin(cfg, f))),
+        "w2": _lin(k2, (H, f, d), std=depth_std),
     }
+    if cfg.n_shared_experts:
+        p["moe"]["shared"] = _mlp_params(ks, cfg,
+                                         f=cfg.n_shared_experts * f)
     p["ln1"] = _norm_p(kn, d, cfg)
     p["ln2"] = _norm_p(kn, d, cfg)
     return p
@@ -192,6 +207,8 @@ def _wdec_layer(key, cfg: ArchConfig):
 
 _LAYER_INIT = {
     "dense": _dense_layer,
+    # A leading dense layer: the stack's attention and an MLP of d_ff.
+    "lead": _dense_layer,
     "moe": _moe_layer,
     "hymba": _hymba_layer,
     "mlstm": _mlstm_layer,
@@ -212,17 +229,15 @@ def init_params(cfg: ArchConfig, key) -> Dict[str, Any]:
     if not cfg.tie_embed:
         params["lm_head"] = _lin(kH, (cfg.d_model, Vp))
 
-    unit = _unit_types(cfg)
     params["blocks"] = {}
-    for t in unit:
+    for t, n in _groups(cfg):
         # crc32, not hash(): str hashing is salted per process
         # (PYTHONHASHSEED), which made init_params draw *different
         # parameters in every process* -- differential tests comparing
         # runs across processes, and anything pinning draw-dependent
         # values, were silently seeded by the interpreter.
         keys = jax.random.split(
-            jax.random.fold_in(kB, zlib.crc32(t.encode()) % 2**31),
-            cfg.n_units,
+            jax.random.fold_in(kB, zlib.crc32(t.encode()) % 2**31), n,
         )
         params["blocks"][t] = jax.vmap(
             lambda k: _LAYER_INIT[t](k, cfg)
@@ -243,25 +258,30 @@ def _unit_types(cfg: ArchConfig) -> Tuple[str, ...]:
     return cfg.unit
 
 
+def _groups(cfg: ArchConfig) -> Tuple[Tuple[str, int], ...]:
+    """(block type, layers stacked) of each group of ``blocks/``: the
+    leading dense layers (``lead``) and each type of the repeating unit."""
+    lead = (("lead", cfg.n_lead),) if cfg.n_lead else ()
+    return lead + tuple((t, cfg.n_units) for t in _unit_types(cfg))
+
+
 # ================================================================ tokens ==
 def _tok():
     return jnp.zeros((N_BWD_EVENTS, STATS_WIDTH), jnp.float32)
 
 
 def _layer_tokens(t: str, cfg: ArchConfig):
-    if t == "dense":
-        names = ["qkv", "proj", "fc1", "fc2"]
+    attn = ["q", "kva", "kvb", "proj"] if cfg.mla else ["qkv", "proj"]
+    if t in ("dense", "lead"):
+        names = attn + ["fc1", "fc2"]
     elif t == "moe":
-        return {
-            "qkv": _tok(),
-            "proj": _tok(),
-            "w1": jnp.zeros(
-                (cfg.n_experts, N_BWD_EVENTS, STATS_WIDTH), jnp.float32
-            ),
-            "w2": jnp.zeros(
-                (cfg.n_experts, N_BWD_EVENTS, STATS_WIDTH), jnp.float32
-            ),
-        }
+        experts = jnp.zeros((cfg.n_held, N_BWD_EVENTS, STATS_WIDTH),
+                            jnp.float32)
+        toks = {n: _tok() for n in attn}
+        toks.update(w1=experts, w2=experts)
+        if cfg.n_shared_experts:
+            toks["shared"] = {"fc1": _tok(), "fc2": _tok()}
+        return toks
     elif t == "hymba":
         names = ["qkv", "proj", "ssm_in", "ssm_out", "fc1", "fc2"]
     elif t == "mlstm":
@@ -278,12 +298,12 @@ def _layer_tokens(t: str, cfg: ArchConfig):
 def make_tokens(cfg: ArchConfig):
     """Zero-valued bwd-stat tokens; grads w.r.t. these carry the backward
     quantization stats out of the train step (see repro.core.linear)."""
-    stack = lambda tree: jax.tree.map(
-        lambda x: jnp.broadcast_to(x, (cfg.n_units, *x.shape)), tree
+    stack = lambda tree, n: jax.tree.map(
+        lambda x: jnp.broadcast_to(x, (n, *x.shape)), tree
     )
     toks = {
         "blocks": {
-            t: stack(_layer_tokens(t, cfg)) for t in _unit_types(cfg)
+            t: stack(_layer_tokens(t, cfg), n) for t, n in _groups(cfg)
         }
     }
     if cfg.family == "audio":
@@ -301,6 +321,8 @@ def _layer_cache_spec(t: str, cfg: ArchConfig, b: int, s: int,
     hkv, hd = cfg.n_kv, cfg.head_dim
     if kv_fp8 and kv_mor:
         raise ValueError("kv_fp8 and kv_mor are mutually exclusive")
+    if cfg.mla and (kv_fp8 or kv_mor):
+        raise NotImplementedError("latent attention keeps a bf16 cache")
     if kv_mor:
         # MoR cache tier (docs/numerics.md): uint8 payload lanes with
         # per-(position, head) representation tags + GAM scales --
@@ -325,10 +347,11 @@ def _layer_cache_spec(t: str, cfg: ArchConfig, b: int, s: int,
         }
     else:
         kv = {
-            "k": jax.ShapeDtypeStruct((b, s, hkv, hd), jnp.bfloat16),
+            "k": jax.ShapeDtypeStruct((b, s, hkv, cfg.qk_head_dim),
+                                      jnp.bfloat16),
             "v": jax.ShapeDtypeStruct((b, s, hkv, hd), jnp.bfloat16),
         }
-    if t in ("dense", "moe"):
+    if t in ("dense", "lead", "moe"):
         return kv
     if t == "hymba":
         di, cw = cfg.mamba_d_inner, cfg.conv_width
@@ -372,12 +395,12 @@ def _layer_cache_spec(t: str, cfg: ArchConfig, b: int, s: int,
 def cache_specs(cfg: ArchConfig, batch: int, seq: int,
                 kv_fp8: bool = False, kv_mor: bool = False):
     """ShapeDtypeStruct pytree for the decode cache (stacked over units)."""
-    stack = lambda spec: jax.tree.map(
-        lambda x: jax.ShapeDtypeStruct((cfg.n_units, *x.shape), x.dtype), spec
+    stack = lambda spec, n: jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct((n, *x.shape), x.dtype), spec
     )
     return {
-        t: stack(_layer_cache_spec(t, cfg, batch, seq, kv_fp8, kv_mor))
-        for t in _unit_types(cfg)
+        t: stack(_layer_cache_spec(t, cfg, batch, seq, kv_fp8, kv_mor), n)
+        for t, n in _groups(cfg)
     }
 
 
@@ -391,7 +414,7 @@ def init_cache(cfg: ArchConfig, batch: int, seq: int, kv_fp8: bool = False,
 
 # =============================================================== forward ==
 def _block_fn(t: str):
-    if t == "dense":
+    if t in ("dense", "lead"):
         return B.dense_block
     if t == "moe":
         return B.moe_block
@@ -609,10 +632,22 @@ def forward(
             all_stats["enc"] = enc_stats
 
     x = constrain(x, "batch", None, None)
-    x, new_cache, stats = _run_stack(
-        _unit_types(cfg), cfg, policy, params["blocks"], tokens["blocks"],
-        x, mode, cache, cur_index, attn_kw, enc_out=enc_out, remat=remat,
-    )
+    stats, new_cache = {}, {}
+    # The leading dense layers, if any, scan as a group of their own
+    # before the repeating unit.
+    for types in (("lead",),) * bool(cfg.n_lead) + (_unit_types(cfg),):
+        def pick(tree, types=types):
+            return None if tree is None else {t: tree[t] for t in types}
+
+        x, nc, st = _run_stack(
+            types, cfg, policy, pick(params["blocks"]),
+            pick(tokens["blocks"]), x, mode, pick(cache), cur_index, attn_kw,
+            enc_out=enc_out, remat=remat,
+        )
+        stats.update(st)
+        new_cache.update(nc or {})
+    if mode == "train":
+        new_cache = None
     all_stats["blocks"] = stats
 
     x = B.norm(params["final_norm"], x, cfg)

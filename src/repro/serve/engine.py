@@ -135,6 +135,11 @@ class Engine:
                 f"family {cfg.family!r} needs a modality frontend the "
                 "engine does not drive (frames/patches inputs)"
             )
+        if cfg.mla or cfg.n_lead:
+            raise NotImplementedError(
+                f"{cfg.name}: the engine does not serve latent attention "
+                "or leading dense layers (training and prefill only)"
+            )
         if scfg.max_seq % scfg.prefill_chunk:
             raise ValueError(
                 f"prefill_chunk {scfg.prefill_chunk} must divide "

@@ -28,7 +28,8 @@ from repro.optim.moments import MomentPolicy
 from repro.robust.guard import GuardPolicy, tree_select
 from repro.sharding import rules as _rules
 
-__all__ = ["TrainConfig", "make_train_step", "summarize_mor_stats"]
+__all__ = ["TrainConfig", "make_train_step", "summarize_mor_stats",
+           "moe_counters"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -69,6 +70,25 @@ class TrainConfig:
     # absorb its own quantization error into EF (no double count).
     # None keeps the unguarded behavior.
     guard: GuardPolicy | None = None
+
+
+@jax.named_scope("mor_quant")
+@jax.named_scope("stats")
+def moe_counters(fwd_stats) -> Dict[str, jnp.ndarray]:
+    """The MoE layers' dispatch counters (``models.blocks.moe_sublayer``)
+    over the step: ``moe_routed_rows`` and ``moe_padded_rows`` summed
+    over the layers, ``moe_load_max_over_mean`` the largest of any
+    layer. Empty for a model without MoE layers."""
+    sums: Dict[str, list] = {}
+    for path, leaf in jax.tree_util.tree_flatten_with_path(fwd_stats)[0]:
+        name = getattr(path[-1], "key", None)
+        if name in ("routed_rows", "padded_rows", "load_max_over_mean"):
+            sums.setdefault(name, []).append(leaf)
+    out = {}
+    for name, leaves in sums.items():
+        red = jnp.max if name == "load_max_over_mean" else jnp.sum
+        out[f"moe_{name}"] = red(jnp.stack([red(l) for l in leaves]))
+    return out
 
 
 @jax.named_scope("mor_quant")
@@ -292,6 +312,7 @@ def make_train_step(
             **summarize_mor_stats(
                 aux.get("mor_fwd"), g_tokens, opt_rows or None
             ),
+            **moe_counters(aux.get("mor_fwd")),
         }
         if new_ef is not None:
             with jax.named_scope("optim"):

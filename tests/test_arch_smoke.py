@@ -21,7 +21,7 @@ from repro.models import (
 from repro.models.transformer import padded_vocab
 
 ARCHS = [
-    "moonshot-v1-16b-a3b", "granite-moe-1b-a400m", "gemma-2b",
+    "moonlight-16b-a3b", "granite-moe-1b-a400m", "gemma-2b",
     "deepseek-coder-33b", "llama3-8b", "minitron-4b", "whisper-tiny",
     "xlstm-350m", "paligemma-3b", "hymba-1.5b",
 ]

@@ -495,3 +495,12 @@ def test_exact_fit_reservation_admitted(dense_model):
     eng.run_to_completion()
     assert fit.done and fit.error is None and len(fit.out) == 9
     assert not eng.rejected
+
+
+def test_engine_refuses_latent_attention():
+    """Moonlight's latent attention and leading dense layer train and
+    prefill, but the engine does not serve them: it says so at once."""
+    cfg = reduced(get_config("moonlight-16b-a3b"))
+    assert cfg.mla and cfg.n_lead
+    with pytest.raises(NotImplementedError, match="latent attention"):
+        Engine(cfg, TENSOR_MOR, params=None)

@@ -29,7 +29,7 @@ from jax.sharding import SingleDeviceSharding
 from repro.core.formats import NVFP4_MICRO
 from repro.kernels import ops as kops
 from repro.kernels.flash_attention import flash_attention_fwd
-from repro.kernels.gam_quant import gam_quant_blocks
+from repro.kernels.gam_quant import gam_quant_blocks, gam_quant_groups
 from repro.kernels.mor_select import mor_select_blocks
 from repro.kernels.ref import (
     MixedOperand,
@@ -96,6 +96,26 @@ def test_gam_quant_compiles(spec, shape):
         lambda x, m: gam_quant_blocks(x, m, block=BLOCK),
         spec(shape, jnp.bfloat16), spec((), jnp.float32),
     )
+
+
+# An MoE layer's expert buffer at Moonlight-16B-A3B's widths: 16,384
+# tokens, 6 copies each at most on the held experts, 8 groups padded to
+# whole blocks; the expert input (d_model), the fused gate and up
+# projections' output and the down projection's input.
+MOE_ROWS = -(-(16384 * 6 + 8 * 127) // 128) * 128
+
+
+@pytest.mark.parametrize("k", [2048, 2816, 1408],
+                         ids=["x", "fc1_out", "fc2_in"])
+@pytest.mark.parametrize("apply", [False, True], ids=["err", "apply"])
+def test_gam_quant_groups_compiles(spec, k, apply):
+    nm = MOE_ROWS // BLOCK[0]
+    args = [spec((MOE_ROWS, k), jnp.bfloat16), spec((nm,), jnp.float32),
+            spec((), jnp.int32)]
+    if apply:
+        args.append(spec((nm,), jnp.int32))
+    _compile(lambda x, m, n, *keep: gam_quant_groups(x, m, n, *keep,
+                                                     block=BLOCK), *args)
 
 
 @pytest.mark.parametrize("emit", ["select", "pack"])
